@@ -12,6 +12,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..obs import span
 from .eve import EVE, RAEConfig
 from .iostats import IOStats
 from .lsm_drtree import LSMDRTree, LSMDRTreeConfig, LSMRTree
@@ -73,7 +74,8 @@ class GloranIndex:
         assert (los < his).all(), "empty range"
         self.index.insert_batch(los, his, smaxs=seqs)
         if self.eve is not None:
-            self.eve.insert_range_batch(los, his, seqs)
+            with span("gloran.eve", n=len(los)):
+                self.eve.insert_range_batch(los, his, seqs)
         self.num_range_deletes += len(los)
 
     # ------------------------------------------------------------- reads
@@ -102,26 +104,29 @@ class GloranIndex:
         never touch the on-disk index either way."""
         keys = np.asarray(keys, dtype=np.uint64)
         entry_seqs = np.asarray(entry_seqs, dtype=np.uint64)
-        if self.eve is not None:
-            maybe = self.eve.maybe_deleted_batch(keys, entry_seqs)
-        else:
-            maybe = np.ones(len(keys), dtype=bool)
-        out = np.zeros(len(keys), dtype=bool)
-        if maybe.any():
-            if level_cov is not None and isinstance(self.index, LSMDRTree):
-                out[maybe] = self.index.covers_batch_cov(
-                    keys[maybe], entry_seqs[maybe], level_cov[maybe])
-            elif query_fn is not None and isinstance(self.index, LSMDRTree):
-                out[maybe] = self.index.covers_batch(
-                    keys[maybe], entry_seqs[maybe], query_fn=query_fn)
-            elif hasattr(self.index, "covers_batch"):
-                out[maybe] = self.index.covers_batch(keys[maybe],
-                                                     entry_seqs[maybe])
+        with span("gloran.validity", n=len(keys)):
+            if self.eve is not None:
+                maybe = self.eve.maybe_deleted_batch(keys, entry_seqs)
             else:
-                out[maybe] = [self.index.covers(int(k), int(s))
+                maybe = np.ones(len(keys), dtype=bool)
+            out = np.zeros(len(keys), dtype=bool)
+            if not maybe.any():
+                return out
+            idx = self.index
+            if level_cov is not None and isinstance(idx, LSMDRTree):
+                out[maybe] = idx.covers_batch_cov(
+                    keys[maybe], entry_seqs[maybe], level_cov[maybe])
+            elif query_fn is not None and isinstance(idx, LSMDRTree):
+                out[maybe] = idx.covers_batch(
+                    keys[maybe], entry_seqs[maybe], query_fn=query_fn)
+            elif hasattr(idx, "covers_batch"):
+                out[maybe] = idx.covers_batch(keys[maybe],
+                                              entry_seqs[maybe])
+            else:
+                out[maybe] = [idx.covers(int(k), int(s))
                               for k, s in zip(keys[maybe],
                                               entry_seqs[maybe])]
-        return out
+            return out
 
     # ---------------------------------------------------- device views
     @property

@@ -24,6 +24,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..obs import span
 from .areas import AreaSet, UKEY
 from .disjointize import disjointize, merge_disjoint
 from .drtree import DRTree
@@ -105,13 +106,14 @@ class LSMDRTree:
     def flush(self) -> None:
         if self.buffer.size == 0:
             return
-        areas = self.buffer.drain_disjoint()
-        self.buffer.clear()
-        tree = self._make_drtree(areas)
-        self.io.write_sequential(len(areas) * 2 * self.config.key_size,
-                                 tag="index_flush")
-        self._push(0, tree)
-        self.epoch += 1
+        with span("gloran.index_flush", records=self.buffer.size):
+            areas = self.buffer.drain_disjoint()
+            self.buffer.clear()
+            tree = self._make_drtree(areas)
+            self.io.write_sequential(
+                len(areas) * 2 * self.config.key_size, tag="index_flush")
+            self._push(0, tree)
+            self.epoch += 1
 
     def _push(self, i: int, tree: DRTree) -> None:
         while len(self.levels) <= i:

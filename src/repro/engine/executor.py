@@ -487,7 +487,8 @@ class ShardExecutor:
             a[:len(ka)] = ka
             b[:len(kb)] = kb
             pa, pb = merge_ranks(a, b, interpret=cfg.interpret,
-                                 device=self.device)
+                                 device=self.device, na=len(ka),
+                                 nb=len(kb))
             self.kernels.merge_calls += 1
             self.kernels.merge_keys += n
             return pa[:len(ka)], pb[:len(kb)]

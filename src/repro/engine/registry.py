@@ -24,7 +24,11 @@ path serves it — identical results, just per-level launches.  GLORAN
 interval columns are *clamped* into u32 like the per-level view (exact
 for u32-range queries); packs past the kernels' VMEM budgets are also
 declined.  Every decline is cached on the same key as a hit, so
-ineligible trees pay one scan, not one per lookup.
+ineligible trees pay one scan, not one per lookup, and is counted once
+per structure by reason in the kernel counters: ``pack_declined_u32``
+(keys or seqs past u32), ``pack_declined_keys`` (past
+``MAX_PACK_KEYS``), ``pack_declined_bytes`` (past the word, area or
+total byte budget).
 """
 
 from __future__ import annotations
@@ -208,6 +212,7 @@ class DeviceFilterRegistry:
             return None
         for _, lvl in lvls:
             if lvl.max_key >= _U32_LIMIT or lvl.max_seq >= _U32_LIMIT:
+                self.counters.pack_declined_u32 += 1
                 return None
         # Budget + uniformity gates run on host-side lengths BEFORE any
         # piece is built, so a permanently over-budget tree never pays a
@@ -222,16 +227,21 @@ class DeviceFilterRegistry:
         # bound is conservative (a decline just means per-level serving).
         area_slots = sum(max(64, _next_pow2(len(g.areas)))
                          for g in (gl_levels or []))
-        if (key_slots > MAX_PACK_KEYS or word_slots > MAX_PACK_WORDS
-                or area_slots > MAX_PACK_AREAS
+        if key_slots > MAX_PACK_KEYS:
+            self.counters.pack_declined_keys += 1
+            return None
+        if (word_slots > MAX_PACK_WORDS or area_slots > MAX_PACK_AREAS
                 or pack_bytes(key_slots, word_slots,
                               area_slots) > MAX_PACK_BYTES):
+            self.counters.pack_declined_bytes += 1
             return None
         pieces = [self._run_piece(lvl) for _, lvl in lvls]
         key_pad = [p.keys.shape[0] for p in pieces]
         word_pad = [p.words.shape[0] for p in pieces]
         gl_pieces = [self._gl_piece(g) for g in (gl_levels or [])]
         gl_pad = [p.lo.shape[0] for p in gl_pieces]
+        key_n = tuple(p.n for p in pieces)
+        gl_n = tuple(p.n for p in gl_pieces)
 
         slots = np.full(len(tree.levels), -1, np.int32)
         for col, (i, _) in enumerate(lvls):
@@ -245,7 +255,7 @@ class DeviceFilterRegistry:
             lseqs=jnp.concatenate([p.seqs for p in pieces]),
             key_off=self._put(
                 np.cumsum([0] + key_pad[:-1]).astype(np.int32)),
-            key_cnt=self._put(np.array([p.n for p in pieces], np.int32)),
+            key_cnt=self._put(np.array(key_n, np.int32)),
             words=jnp.concatenate([p.words for p in pieces]),
             word_off=self._put(
                 np.cumsum([0] + word_pad[:-1]).astype(np.int32)),
@@ -259,13 +269,13 @@ class DeviceFilterRegistry:
             gl_off=self._put(
                 np.cumsum([0] + gl_pad[:-1]).astype(np.int32)
                 if gl_pieces else np.zeros(0, np.int32)),
-            gl_cnt=self._put(
-                np.array([p.n for p in gl_pieces], np.int32)),
+            gl_cnt=self._put(np.array(gl_n, np.int32)),
             L=len(pieces), H=H, G=len(gl_pieces),
             steps_keys=_steps(max(key_pad)),
             steps_gl=_steps(max(gl_pad) if gl_pad else 1),
             key_pad=tuple(key_pad), word_pad=tuple(word_pad),
-            gl_pad=tuple(gl_pad))
+            gl_pad=tuple(gl_pad),
+            key_sizes=key_n, gl_sizes=gl_n)
         self.counters.cascade_packs += 1
         return CascadeView(state=state, slots=slots,
                            has_gloran=gl_levels is not None)

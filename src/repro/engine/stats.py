@@ -26,6 +26,12 @@ class KernelCounters:
     cascade_calls: int = 0      # fused lookup-cascade launches
     cascade_queries: int = 0    # lookups answered by the cascade
     cascade_packs: int = 0      # registry device-state (re)packs
+    # Packs the registry declined, once per new tree structure, by
+    # reason: keys/seqs past u32, key slots past MAX_PACK_KEYS, or the
+    # word/area/total byte budgets.
+    pack_declined_u32: int = 0
+    pack_declined_keys: int = 0
+    pack_declined_bytes: int = 0
     upload_bytes: int = 0       # host->device bytes moved by the packs
     # upload_bytes split by destination device ("cpu:0", ... — "host"
     # when packs stay on the default device): the per-device ledger the
@@ -44,6 +50,9 @@ class KernelCounters:
         self.cascade_calls += other.cascade_calls
         self.cascade_queries += other.cascade_queries
         self.cascade_packs += other.cascade_packs
+        self.pack_declined_u32 += other.pack_declined_u32
+        self.pack_declined_keys += other.pack_declined_keys
+        self.pack_declined_bytes += other.pack_declined_bytes
         self.upload_bytes += other.upload_bytes
         for dev, nbytes in other.upload_bytes_by_device.items():
             self.upload_bytes_by_device[dev] = \
@@ -73,6 +82,9 @@ class KernelCounters:
             "cascade_calls": self.cascade_calls,
             "cascade_queries": self.cascade_queries,
             "cascade_packs": self.cascade_packs,
+            "pack_declined_u32": self.pack_declined_u32,
+            "pack_declined_keys": self.pack_declined_keys,
+            "pack_declined_bytes": self.pack_declined_bytes,
             "upload_bytes": self.upload_bytes,
             "upload_bytes_by_device": dict(sorted(
                 self.upload_bytes_by_device.items())),

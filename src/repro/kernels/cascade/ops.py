@@ -73,6 +73,10 @@ class CascadeState:
     key_pad: tuple        # static pow2 per-level padded sizes (XLA form)
     word_pad: tuple
     gl_pad: tuple
+    # Host copies of key_cnt / gl_cnt, kept by the registry when it
+    # packs, so a launch's span carries them without a device read.
+    key_sizes: tuple = ()
+    gl_sizes: tuple = ()
 
 
 _cascade_xla = jax.jit(cascade_flat, static_argnames=(
@@ -102,7 +106,8 @@ def cascade_lookup(qkey32, qhash32, qseq32, qres, state: CascadeState, *,
     candidate positions.
     """
     with span("kernel.cascade", n=len(qkey32), levels=state.L,
-              gl_levels=state.G):
+              gl_levels=state.G, key_cnt=state.key_sizes,
+              gl_cnt=state.gl_sizes, hashes=state.H):
         return _cascade_lookup(qkey32, qhash32, qseq32, qres, state,
                                block_rows=block_rows, interpret=interpret,
                                compiled=compiled, device=device)

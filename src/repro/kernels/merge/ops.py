@@ -60,7 +60,8 @@ def _rank(queries: np.ndarray, arr: np.ndarray, *, leq: bool,
 
 def merge_ranks(ka: np.ndarray, kb: np.ndarray, *, block_rows: int = 8,
                 interpret: bool | None = None,
-                compiled: bool | None = None, device=None):
+                compiled: bool | None = None, device=None,
+                na: int | None = None, nb: int | None = None):
     """Merged-output positions of two key-sorted uint32 runs.
 
     Returns ``(pa, pb)`` int64 numpy arrays: ``pa[i]`` is the slot of
@@ -72,10 +73,13 @@ def merge_ranks(ka: np.ndarray, kb: np.ndarray, *, block_rows: int = 8,
     Pallas kernel; ``None`` picks from the backend (XLA on a TPU, the
     interpreted Pallas kernel elsewhere).  ``device`` commits both runs
     to one XLA device so the launch runs there (per-shard placement).
+    ``na``/``nb`` name the real run lengths when the caller has padded
+    the runs; they only label the ``kernel.merge`` span.
     """
     ka = np.asarray(ka)
     kb = np.asarray(kb)
-    with span("kernel.merge", n=len(ka) + len(kb)):
+    with span("kernel.merge", na=len(ka) if na is None else na,
+              nb=len(kb) if nb is None else nb):
         return _merge_ranks(ka, kb, block_rows=block_rows,
                             interpret=interpret, compiled=compiled,
                             device=device)

@@ -4,9 +4,11 @@ Three pieces, one import surface:
 
   ``span`` / ``Tracer``      begin/end spans on ``time.perf_counter``
                              from ``Engine.submit`` down to kernel
-                             dispatch, exported as Chrome trace-event
-                             JSON (loads in Perfetto with one track per
-                             shard worker thread).  A process-global
+                             dispatch, with each span's thread CPU time,
+                             mirrored into the JAX profiler's trace and
+                             exported as Chrome trace-event JSON (loads
+                             in Perfetto with one track per shard
+                             worker thread).  A process-global
                              no-op tracer is the default — the off
                              switch costs nothing measurable (env
                              ``REPRO_TRACE=1`` turns recording on),
@@ -22,8 +24,8 @@ See docs/OBSERVABILITY.md for usage and the metric namespace.
 from .hist import LatencyHistogram
 from .metrics import MetricsRegistry
 from .tracer import (NULL_TRACER, NullTracer, Tracer, enabled, get_tracer,
-                     instant, set_tracer, span, tracing_enabled)
+                     set_tracer, span, tracing_enabled)
 
 __all__ = ["LatencyHistogram", "MetricsRegistry", "NULL_TRACER",
-           "NullTracer", "Tracer", "enabled", "get_tracer", "instant",
-           "set_tracer", "span", "tracing_enabled"]
+           "NullTracer", "Tracer", "enabled", "get_tracer", "set_tracer",
+           "span", "tracing_enabled"]

@@ -9,12 +9,17 @@ tracer decides what those calls cost:
                manager, no lock, no allocation beyond the (empty) kwargs
                dict — the instrumented path stays within noise of an
                uninstrumented one (gated in ``scripts/check.sh``),
-  Tracer       records (name, begin, end, thread) per span, thread-safe,
-               bounded (drops past ``max_events``), exportable as Chrome
-               trace-event JSON that loads directly in Perfetto / about:
-               //tracing, with one named track per thread — the shard
-               worker pools are named ``shard-N``, so per-shard timelines
-               come out of the box.
+  Tracer       records (name, begin, end, thread) per span, and the
+               thread's CPU time for the planner's spans (``CPU_SPANS``),
+               thread-safe, bounded (drops past ``max_events``),
+               exportable as Chrome trace-event JSON that loads directly
+               in Perfetto / about://tracing, with one named track per
+               thread — the shard worker pools are named ``shard-N``, so
+               per-shard timelines come out of the box.  Each span also
+               opens a ``jax.profiler.TraceAnnotation`` of the same name
+               on its thread, so under ``jax.profiler.start_trace`` the
+               spans land in the profile's host plane on the clock the
+               device planes use.
 
 Enable globally with env ``REPRO_TRACE=1`` (read once at import), or per
 scope with ``set_tracer(Tracer())`` / the ``enabled()`` context manager.
@@ -53,9 +58,6 @@ class NullTracer:
     def span(self, name: str, **attrs) -> _NullSpan:
         return _NULL_SPAN
 
-    def instant(self, name: str, **attrs) -> None:
-        pass
-
     def events(self) -> list:
         return []
 
@@ -66,32 +68,74 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
-class _Span:
-    """One open span; records on ``__exit__`` (begin/end always pair)."""
+_perf_counter = time.perf_counter
+_thread_time_ns = time.thread_time_ns
+_current_thread = threading.current_thread
 
-    __slots__ = ("tracer", "name", "attrs", "t0")
+# Span-name prefixes whose spans also take their thread's CPU time.  A
+# thread-clock read is a system call, and while the shard threads run
+# it costs several times what it costs alone, more than the rest of a
+# recorded span; so only the planner's spans take it, whose GIL wait
+# the planner is judged by.
+CPU_SPANS = ("plan.",)
+
+
+class _Span:
+    """One open span; records on ``__exit__`` (begin/end always pair).
+
+    Inside a profiler session the span opens a profiler annotation
+    before its clocks are read and closes it after, so the annotation's
+    interval holds the recorded one; outside a session it opens none."""
+
+    __slots__ = ("tracer", "name", "attrs", "timed", "ann", "t0", "c0")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self.tracer = tracer
         self.name = name
         self.attrs = attrs
-        self.t0 = time.perf_counter()
+        self.timed = name.startswith(CPU_SPANS)
 
     def __enter__(self) -> "_Span":
+        ann = self.tracer._annotation
+        if ann.is_enabled():
+            ann = ann(self.name)
+            ann.__enter__()
+            self.ann = ann
+        else:
+            self.ann = None
+        if self.timed:
+            self.c0 = _thread_time_ns()
+        self.t0 = _perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self.tracer._record(self.name, self.t0, time.perf_counter(),
-                            self.attrs)
+        t1 = _perf_counter()
+        cpu = (_thread_time_ns() - self.c0) * 1e-9 if self.timed else None
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
+        # Recorded without the tracer's lock: list.append is atomic, so
+        # spans closing on several threads at once never tear, and the
+        # bound may overshoot by a span per thread racing past the check.
+        tr = self.tracer
+        if len(tr._events) >= tr.max_events:
+            tr.dropped += 1
+            return False
+        th = _current_thread()
+        tr._events.append((self.name, self.t0, t1, cpu, th.ident, th.name,
+                           self.attrs))
         return False
 
 
 class Tracer:
     """Thread-safe span recorder on the monotonic ``perf_counter`` clock.
 
-    Every span is stored as a completed ``(name, t0, t1, tid, thread
-    name, attrs)`` tuple — begin/end pair by construction, timestamps are
-    monotonic and shared across threads (one clock).  Memory is bounded:
+    Every span is stored as a completed ``(name, t0, t1, cpu, tid,
+    thread name, attrs)`` tuple — begin/end pair by construction,
+    timestamps are monotonic and shared across threads (one clock);
+    ``cpu`` is the CPU time the span's thread ran inside it
+    (``time.thread_time_ns``) for a span whose name starts with one of
+    ``CPU_SPANS``, else None; wall minus ``cpu`` is the time the thread
+    waited: on I/O, on the device, or for the GIL.  Memory is bounded:
     past ``max_events`` spans, new ones are counted in ``dropped`` and
     discarded (the trace stays loadable, never OOMs a long run).
     """
@@ -109,23 +153,14 @@ class Tracer:
         self._foreign: list[tuple] = []
         self._lock = threading.Lock()
         self._base = time.perf_counter()
+        # Imported here, not at module import: the NullTracer path never
+        # touches JAX.
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
 
     # ----------------------------------------------------------- record
     def span(self, name: str, **attrs) -> _Span:
         return _Span(self, name, attrs)
-
-    def instant(self, name: str, **attrs) -> None:
-        t = time.perf_counter()
-        self._record(name, t, t, attrs)
-
-    def _record(self, name: str, t0: float, t1: float,
-                attrs: dict) -> None:
-        th = threading.current_thread()
-        with self._lock:
-            if len(self._events) >= self.max_events:
-                self.dropped += 1
-                return
-            self._events.append((name, t0, t1, th.ident, th.name, attrs))
 
     def clear(self) -> None:
         with self._lock:
@@ -140,9 +175,12 @@ class Tracer:
         shipping format a shard worker sends home with each reply.  The
         epoch is kept, so successive drains stay on one timeline."""
         with self._lock:
-            snap, self._events = self._events, []
-        return [[n, t0, t1, tid, tname, attrs]
-                for n, t0, t1, tid, tname, attrs in snap]
+            # Copy, then cut what was copied: a span recorded meanwhile
+            # stays for the next drain.
+            snap = self._events[:]
+            del self._events[:len(snap)]
+        return [[n, t0, t1, cpu, tid, tname, attrs]
+                for n, t0, t1, cpu, tid, tname, attrs in snap]
 
     def absorb(self, rows: list, *, pid: int,
                process_name: str | None = None) -> None:
@@ -157,21 +195,26 @@ class Tracer:
                     continue
                 self._foreign.append((int(pid), process_name, r[0],
                                       float(r[1]), float(r[2]),
-                                      int(r[3]), r[4], r[5] or {}))
+                                      None if r[3] is None
+                                      else float(r[3]),
+                                      int(r[4]), r[5], r[6] or {}))
 
     # ------------------------------------------------------------ views
     def events(self) -> list[dict]:
-        """Completed spans as dicts (seconds on the tracer's clock)."""
+        """Completed spans as dicts (seconds on the tracer's clock;
+        ``cpu``: seconds the thread ran inside the span, or None)."""
         with self._lock:
             snap = list(self._events)
-        return [{"name": n, "t0": t0, "t1": t1, "tid": tid,
+        return [{"name": n, "t0": t0, "t1": t1, "cpu": cpu, "tid": tid,
                  "thread": tname, "attrs": attrs}
-                for n, t0, t1, tid, tname, attrs in snap]
+                for n, t0, t1, cpu, tid, tname, attrs in snap]
 
     def chrome_events(self) -> list[dict]:
         """Chrome trace-event list: complete ('X') events in microseconds
         relative to the tracer epoch, plus thread/process name metadata
-        so Perfetto labels each shard worker's track."""
+        so Perfetto labels each shard worker's track.  Each event's
+        ``args`` holds the span's attrs, and ``cpu_ms`` where the span
+        took its thread's CPU time."""
         with self._lock:
             snap = list(self._events)
             foreign = list(self._foreign)
@@ -180,7 +223,7 @@ class Tracer:
                 "args": {"name": "repro-engine"}}]
         seen: dict[tuple, str] = {}
 
-        def emit(pid, name, t0, t1, tid, tname, attrs):
+        def emit(pid, name, t0, t1, cpu, tid, tname, attrs):
             if (pid, tid) not in seen:
                 seen[(pid, tid)] = tname
                 out.append({"name": "thread_name", "ph": "M", "pid": pid,
@@ -189,20 +232,22 @@ class Tracer:
                   "pid": pid, "tid": tid,
                   "ts": round((t0 - base) * 1e6, 3),
                   "dur": round((t1 - t0) * 1e6, 3)}
+            if cpu is not None:
+                attrs = {**attrs, "cpu_ms": round(cpu * 1e3, 6)}
             if attrs:
                 ev["args"] = attrs
             out.append(ev)
 
-        for name, t0, t1, tid, tname, attrs in snap:
-            emit(1, name, t0, t1, tid, tname, attrs)
+        for name, t0, t1, cpu, tid, tname, attrs in snap:
+            emit(1, name, t0, t1, cpu, tid, tname, attrs)
         pids_named: set[int] = set()
-        for pid, pname, name, t0, t1, tid, tname, attrs in foreign:
+        for pid, pname, name, t0, t1, cpu, tid, tname, attrs in foreign:
             if pid not in pids_named:
                 pids_named.add(pid)
                 out.append({"name": "process_name", "ph": "M",
                             "pid": pid, "tid": 0,
                             "args": {"name": pname or f"pid {pid}"}})
-            emit(pid, name, t0, t1, tid, tname, attrs)
+            emit(pid, name, t0, t1, cpu, tid, tname, attrs)
         return out
 
     def export_chrome(self, path: str) -> dict:
@@ -243,11 +288,6 @@ def span(name: str, **attrs):
     costly to compute should be guarded with ``tracing_enabled()``.
     """
     return _TRACER.span(name, **attrs)
-
-
-def instant(name: str, **attrs) -> None:
-    """Record a zero-duration marker on the global tracer."""
-    _TRACER.instant(name, **attrs)
 
 
 def tracing_enabled() -> bool:

@@ -1,15 +1,19 @@
 """Observability layer: tracer spans + Chrome export, latency
 histograms, the metrics registry, and their engine integration."""
 
+import glob
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
 
 from repro import obs
 from repro.analysis.report import trace_report
+from repro.core import GloranConfig, LSMDRTreeConfig, RAEConfig
 from repro.engine import Engine, EngineConfig, OpBatch
+from repro.engine import executor as executor_mod
 from repro.lsm import LSMConfig
 from repro.obs import (LatencyHistogram, MetricsRegistry, NULL_TRACER,
                        Tracer)
@@ -108,6 +112,265 @@ def test_tracer_bounded_drops_not_grows():
             pass
     assert len(tr.events()) == 10
     assert tr.dropped == 15
+
+
+def _profiled(tracer, log_dir):
+    """Run two nested spans under ``tracer`` inside a JAX profiler
+    session; returns the tracer's events and the profile's host plane
+    events named ``probe.*``, as ``(line index, name, start_ns,
+    duration_ns)``."""
+    import jax
+    prev = obs.get_tracer()
+    obs.set_tracer(tracer)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.enable_hlo_proto = False
+    try:
+        jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+        try:
+            with obs.span("probe.outer", n=1):
+                time.sleep(0.005)
+                with obs.span("probe.inner"):
+                    time.sleep(0.01)
+                time.sleep(0.005)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs.set_tracer(prev)
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    found = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for i, line in enumerate(plane.lines):
+                found += [(i, e.name, e.start_ns, e.duration_ns)
+                          for e in line.events
+                          if e.name.startswith("probe.")]
+    return tracer.events(), found
+
+
+def test_spans_mirror_into_profiler_host_plane(tmp_path):
+    """A recorded span and a nested one land in the profile's host plane
+    with the same names, nesting and thread line, on durations within 5%
+    or 100 us of the tracer's; under the NullTracer none does.  Another
+    thread may take the GIL between an annotation's edge and the span's
+    clock read, for up to a switch interval, so one of up to five
+    sessions has to meet the durations; every session meets the rest."""
+    close = False
+    for attempt in range(5):
+        evs, found = _profiled(Tracer(), tmp_path / f"on{attempt}")
+        mine = {e["name"]: e["t1"] - e["t0"] for e in evs}
+        assert set(mine) == {"probe.outer", "probe.inner"}
+        by = {name: (line, a, d) for line, name, a, d in found}
+        assert sorted(by) == sorted(mine) and len(found) == 2
+        (lo, ao, do), (li, ai, di) = by["probe.outer"], by["probe.inner"]
+        assert lo == li
+        assert ao <= ai and ai + di <= ao + do
+        close = all(abs(d * 1e-9 - mine[name])
+                    <= max(0.05 * mine[name], 1e-4)
+                    for name, (_, _, d) in by.items())
+        if close:
+            break
+    assert close, (by, mine)
+    _, found = _profiled(NULL_TRACER, tmp_path / "off")
+    assert found == []
+
+
+def _busy(seconds):
+    end = time.perf_counter() + seconds
+    x = 0
+    while time.perf_counter() < end:
+        x += 1
+    return x
+
+
+def test_span_cpu_time_separates_waiting_from_running():
+    """``cpu`` is the thread's CPU time inside a ``plan.*`` span: a sleep
+    records almost none, a busy loop about its wall time.  Each busy
+    loop's ``cpu`` must match the thread's CPU clock read around the
+    span; as a loaded host may take the core away during a loop, one of
+    the loops run over up to 5 s has to run within 20% of its wall
+    time.  Other spans read no CPU clock."""
+    tr = Tracer()
+    with tr.span("plan.sleep"):
+        time.sleep(0.02)
+    with tr.span("shard.sleep"):
+        pass
+    ev, untimed = tr.events()
+    assert ev["t1"] - ev["t0"] >= 0.02 and ev["cpu"] < 0.005
+    assert untimed["cpu"] is None
+    (x,) = [e for e in tr.chrome_events()
+            if e["ph"] == "X" and e["name"] == "shard.sleep"]
+    assert "args" not in x
+    close = False
+    deadline = time.perf_counter() + 5.0
+    while not close and time.perf_counter() < deadline:
+        tr.clear()
+        c0 = time.thread_time()
+        with tr.span("plan.busy"):
+            _busy(0.02)
+        held = time.thread_time() - c0
+        (ev,) = tr.events()
+        wall = ev["t1"] - ev["t0"]
+        assert ev["cpu"] <= held + 1e-6 and ev["cpu"] <= wall + 1e-3
+        assert held - ev["cpu"] < 1e-3, (held, ev["cpu"])
+        close = abs(ev["cpu"] - wall) <= 0.2 * wall
+    assert close, (ev["cpu"], wall)
+    doc = tr.chrome_events()
+    (x,) = [e for e in doc if e["ph"] == "X"]
+    assert x["args"]["cpu_ms"] == pytest.approx(ev["cpu"] * 1e3, abs=1e-5)
+
+
+def _gloran_engine(**cfg):
+    """2 pipelined GLORAN shards with every kernel gate at its floor and
+    a small index buffer, so lookups take the cascade with a GLORAN
+    level and compactions take the merge kernel."""
+    d = dict(pipeline=True, procs=0, kernel_min_batch=1,
+             kernel_min_areas=1, kernel_min_filter=1, kernel_min_merge=16)
+    d.update(cfg)
+    return Engine(num_shards=2, strategy="gloran",
+                  lsm_config=LSMConfig(buffer_capacity=64, size_ratio=3,
+                                       key_size=16, value_size=48,
+                                       block_size=512,
+                                       key_universe=UNIVERSE),
+                  gloran_config=GloranConfig(
+                      index=LSMDRTreeConfig(buffer_capacity=16,
+                                            size_ratio=3, key_size=16,
+                                            block_size=512),
+                      eve=RAEConfig(capacity=64, key_universe=UNIVERSE)),
+                  config=EngineConfig(**d))
+
+
+def _drive(eng, rng, model, rounds=4):
+    """Puts, range deletes and lookups through ``Engine.submit``; each
+    lookup batch is checked against ``model``, a dict."""
+    for _ in range(rounds):
+        keys = rng.integers(0, 4000, size=300).astype(np.uint64)
+        vals = keys * np.uint64(7) + np.uint64(1)
+        eng.submit(OpBatch.puts(keys, vals)).get_results()
+        model.update(zip(keys.tolist(), vals.tolist()))
+        los = rng.integers(0, 3900, size=20).astype(np.uint64)
+        his = los + rng.integers(1, 40, size=20).astype(np.uint64)
+        eng.submit(OpBatch.range_deletes(zip(los.tolist(), his.tolist()))
+                   ).get_results()
+        for lo, hi in zip(los.tolist(), his.tolist()):
+            for k in [k for k in model if lo <= k < hi]:
+                del model[k]
+        probe = rng.integers(0, 4000, size=400).astype(np.uint64)
+        found, got = eng.submit(OpBatch.gets(probe)).get_results()
+        want = [model.get(k) for k in probe.tolist()]
+        assert found.tolist() == [w is not None for w in want]
+        assert got[found].tolist() == [w for w in want if w is not None]
+
+
+def test_kernel_spans_carry_call_sizes(monkeypatch):
+    """Every ``kernel.cascade`` span's per-level sizes and hash count
+    equal the packed state's, read back from the device, and every
+    ``kernel.merge`` span's run lengths equal the runs before padding."""
+    seen = {"cascade": [], "merge": []}
+    cascade, merge = executor_mod.cascade_lookup, executor_mod.merge_ranks
+
+    def rec_cascade(*a, **kw):
+        st = a[4]
+        seen["cascade"].append((tuple(np.asarray(st.key_cnt).tolist()),
+                                tuple(np.asarray(st.gl_cnt).tolist()),
+                                st.H))
+        return cascade(*a, **kw)
+
+    def rec_merge(ka, kb, **kw):
+        seen["merge"].append((int(np.searchsorted(ka, 0xFFFFFFFF)),
+                              int(np.searchsorted(kb, 0xFFFFFFFF))))
+        return merge(ka, kb, **kw)
+
+    monkeypatch.setattr(executor_mod, "cascade_lookup", rec_cascade)
+    monkeypatch.setattr(executor_mod, "merge_ranks", rec_merge)
+    eng = _gloran_engine()
+    with obs.enabled() as tr:
+        _drive(eng, np.random.default_rng(3), {})
+        eng.drain()
+    spans = {"cascade": [], "merge": []}
+    for e in tr.events():
+        a = e["attrs"]
+        if e["name"] == "kernel.cascade":
+            spans["cascade"].append((tuple(a["key_cnt"]),
+                                     tuple(a["gl_cnt"]), a["hashes"]))
+        elif e["name"] == "kernel.merge":
+            spans["merge"].append((a["na"], a["nb"]))
+    assert seen["cascade"] and seen["merge"]
+    assert any(gl for _, gl, _ in seen["cascade"])
+    for k in seen:
+        assert sorted(spans[k]) == sorted(seen[k]), k
+
+
+def _parent(e, evs, name):
+    """The ``name`` span on ``e``'s thread that holds ``e``, or None."""
+    return next((p for p in evs if p["name"] == name
+                 and p["tid"] == e["tid"] and p["t0"] <= e["t0"]
+                 and e["t1"] <= p["t1"]), None)
+
+
+def test_shard_host_spans_nest_under_their_step():
+    """The spans of a shard's host work nest under the plan step that
+    runs them, on the shard's worker thread."""
+    eng = _gloran_engine()
+    with obs.enabled() as tr:
+        _drive(eng, np.random.default_rng(4), {}, rounds=2)
+        eng.drain()
+    evs = tr.events()
+    # The validity probe also runs inside compactions, to drop the
+    # entries range deletes have covered.
+    under = {"gloran.eve": ("shard.range_delete",),
+             "lsm.get.mem": ("shard.get",), "lsm.get.levels": ("shard.get",),
+             "gloran.validity": ("shard.get", "lsm.compact")}
+    for child, parents in under.items():
+        mine = [e for e in evs if e["name"] == child]
+        assert any(_parent(e, evs, parents[0]) for e in mine), child
+        for e in mine:
+            assert e["thread"].startswith("shard-"), (child, e["thread"])
+            assert any(_parent(e, evs, p) for p in parents), (child, e)
+    flushes = [e for e in evs if e["name"] == "gloran.index_flush"]
+    assert flushes and all(e["attrs"]["records"] == 16 for e in flushes)
+    assert all(_parent(e, evs, "shard.range_delete") for e in flushes)
+
+
+def test_engine_cpu_time_on_planner_spans_only():
+    """Every ``plan.compile`` span carries
+    ``cpu``, at most its wall time; no other span of the engine reads
+    the thread's CPU clock."""
+    eng = _gloran_engine()
+    with obs.enabled() as tr:
+        _drive(eng, np.random.default_rng(6), {}, rounds=2)
+        eng.drain()
+    evs = tr.events()
+    plans = [e for e in evs if e["name"] == "plan.compile"]
+    assert plans and len(plans) < len(evs)
+    for e in evs:
+        if e["name"] == "plan.compile":
+            assert 0 <= e["cpu"] <= e["t1"] - e["t0"] + 1e-3, e
+        else:
+            assert e["cpu"] is None, e
+
+
+def test_declined_packs_counted_once_by_reason(monkeypatch):
+    """Past ``MAX_PACK_KEYS`` the registry declines the pack: the decline
+    is counted once per new structure, shows in ``stats()["metrics"]``,
+    and the per-level path still answers exactly."""
+    from repro.engine import registry
+    monkeypatch.setattr(registry, "MAX_PACK_KEYS", 64)
+    eng = _gloran_engine()
+    model: dict = {}
+    rng = np.random.default_rng(5)
+    _drive(eng, rng, model)
+    snap = eng.kernel_counters.snapshot()
+    assert snap["pack_declined_keys"] > 0
+    assert snap["pack_declined_u32"] == snap["pack_declined_bytes"] == 0
+    probe = np.array(sorted(model)[:300], np.uint64)
+    for _ in range(2):  # same structure: the cached decline is not recounted
+        found, _ = eng.get_batch(probe)
+        assert found.all()
+    assert eng.kernel_counters.pack_declined_keys == \
+        snap["pack_declined_keys"]
+    assert eng.stats()["metrics"]["kernels.pack_declined_keys"] == \
+        snap["pack_declined_keys"]
 
 
 # ----------------------------------------------------------- histograms

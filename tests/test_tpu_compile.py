@@ -154,7 +154,8 @@ def _call(kernel, **ask):
     """One small dispatch of ``kernel`` through its public entry."""
     k = np.arange(256, dtype=np.uint32)
     if kernel == "cascade":
-        state = SimpleNamespace(L=1, G=0)
+        state = SimpleNamespace(L=1, G=0, H=1, key_sizes=(256,),
+                                gl_sizes=())
         return cascade_ops.cascade_lookup(k, k, k, k > 0, state, **ask)
     if kernel == "bloom":
         return bloom_ops.bloom_probe(k, np.zeros(64, np.uint32),
