@@ -632,6 +632,13 @@ class Engine:
         if lsm_m:
             out["lsm"] = lsm_m
             m.absorb("lsm", lsm_m)
+        # Write runs the shard executors applied fused, and their steps.
+        ex: dict = {}
+        for f in fulls:
+            for k, v in f["executor"].items():
+                ex[k] = ex.get(k, 0) + v
+        out["executor"] = ex
+        m.absorb("executor", ex)
         wals = [f["wal"] for f in fulls if f["wal"] is not None]
         if wals:
             agg: dict = {}

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
+from itertools import groupby
 
 import numpy as np
 
@@ -68,6 +69,7 @@ from ..durable.manifest import structure_fingerprint
 from ..durable.wal import FRAME_BATCH
 
 _QUERY_TILE = 1024  # block_rows(8) x LANES(128): one grid row
+_WRITE_KINDS = (OP_PUT, OP_DELETE, OP_RANGE_DELETE)
 
 
 @dataclass
@@ -166,6 +168,9 @@ class ShardExecutor:
         self.shard_id = 0
         # Background compaction scheduler (None = inline flush path).
         self.scheduler = None
+        # Fused write runs (``_write_run``) and the plan steps they held.
+        self.write_runs_fused = 0
+        self.write_steps_fused = 0
         # Compactions route their two-run merge through the gated
         # merge-rank kernel closure (bit-exact with the host
         # searchsorted pair — same hook the scan tournament uses).
@@ -311,6 +316,8 @@ class ShardExecutor:
             "sched": (self.scheduler.counters()
                       if self.scheduler is not None else None),
             "wal": self.wal.counters() if self.wal is not None else None,
+            "executor": {"write_runs_fused": self.write_runs_fused,
+                         "write_steps_fused": self.write_steps_fused},
             "lsm": {
                 "compaction_bytes": {int(i): int(b) for i, b in
                                      tree.compaction_bytes.items()},
@@ -341,7 +348,6 @@ class ShardExecutor:
         """
         t0 = time.perf_counter()
         payloads: list = []
-        io_wait = self.config.io_wait_s
         with span("shard.plan", shard=sp.shard, batch=sp.seq,
                   steps=len(sp.steps), n_ops=sp.n_ops,
                   device="host" if self.device is None else
@@ -358,35 +364,132 @@ class ShardExecutor:
             self.run_scheduler()
             fp0 = (structure_fingerprint(self.tree)
                    if self.manifest is not None else None)
-            for step in sp.steps:
-                with span("shard." + KIND_NAMES[step.kind], n=len(step),
-                          shard=sp.shard, batch=sp.seq):
-                    io0 = self.tree.io.total if io_wait > 0.0 else 0
-                    if step.kind == OP_PUT:
-                        self.put_batch(step.keys, step.vals)
-                    elif step.kind == OP_DELETE:
-                        self.delete_batch(step.keys)
-                    elif step.kind == OP_GET:
-                        found, vals = self.get_batch(step.keys)
-                        payloads.append((OP_GET, step.idx, found, vals))
-                    elif step.kind == OP_RANGE_SCAN:
-                        res = self.range_scan_batch(
-                            list(zip(step.los.tolist(),
-                                     step.his.tolist())))
-                        payloads.append((OP_RANGE_SCAN, step.idx, res))
-                    else:  # OP_RANGE_DELETE (bounds clipped per shard)
-                        self.range_delete_arrays(step.los, step.his)
-                    if io_wait > 0.0:
-                        # Timed-I/O mode: serve the step's charged
-                        # blocks as a real wait.  Charges are untouched
-                        # (the ledger stays bit-identical); only wall
-                        # time grows, and it overlaps across shard
-                        # workers — sleep releases the GIL.
-                        dio = self.tree.io.total - io0
-                        if dio:
-                            time.sleep(dio * io_wait)
+            # Maximal runs of write steps with no read between them, and
+            # the read steps between those runs.
+            for writes, run in groupby(
+                    sp.steps, key=lambda s: s.kind in _WRITE_KINDS):
+                run = list(run)
+                if writes and self._fuses(run):
+                    self._write_run(sp, run)
+                else:
+                    for step in run:
+                        self._run_step(sp, step, payloads)
             self._maybe_record_structure(fp0, "plan")
         return payloads, time.perf_counter() - t0
+
+    def _run_step(self, sp: ShardPlan, step, payloads: list) -> None:
+        """Apply one plan step on its own."""
+        with span("shard." + KIND_NAMES[step.kind], n=len(step),
+                  shard=sp.shard, batch=sp.seq):
+            io0 = self.tree.io.total
+            if step.kind == OP_PUT:
+                self.put_batch(step.keys, step.vals)
+            elif step.kind == OP_DELETE:
+                self.delete_batch(step.keys)
+            elif step.kind == OP_GET:
+                found, vals = self.get_batch(step.keys)
+                payloads.append((OP_GET, step.idx, found, vals))
+            elif step.kind == OP_RANGE_SCAN:
+                res = self.range_scan_batch(
+                    list(zip(step.los.tolist(), step.his.tolist())))
+                payloads.append((OP_RANGE_SCAN, step.idx, res))
+            else:  # OP_RANGE_DELETE (bounds clipped per shard)
+                self.range_delete_arrays(step.los, step.his)
+            self._io_wait(io0)
+
+    def _io_wait(self, io0: int) -> None:
+        """Timed-I/O mode: serve the blocks charged since ``io0`` as a
+        real wait.  Charges are untouched (the ledger stays
+        bit-identical); only wall time grows, and it overlaps across
+        shard workers — sleep releases the GIL."""
+        io_wait = self.config.io_wait_s
+        if io_wait > 0.0:
+            dio = self.tree.io.total - io0
+            if dio:
+                time.sleep(dio * io_wait)
+
+    # ------------------------------------------------ fused write runs
+    def _fuses(self, run: list) -> bool:
+        """Whether a run of write steps is applied fused: it mixes range
+        deletes with memtable writes on a GLORAN tree, whose range
+        deletes never enter the memtable.  Every other run (single-kind,
+        or range deletes that are memtable writes or point ops under the
+        other strategies) is applied step by step."""
+        kinds = {step.kind for step in run}
+        return (self.tree.strategy == "gloran" and OP_RANGE_DELETE in kinds
+                and (OP_PUT in kinds or OP_DELETE in kinds))
+
+    def _write_run(self, sp: ShardPlan, run: list) -> None:
+        """Apply a run of interleaved memtable writes and GLORAN range
+        deletes as one index call and one memtable call per same-kind
+        stretch, with exactly the state step-by-step application gives.
+
+        Under GLORAN the memtable's flush points depend only on the
+        memtable writes and the index's only on the range deletes; the
+        two meet where a memtable flush's bottom compaction probes the
+        index and sets its GC floor.  So the run is cut into segments
+        that end with the step during which the memtable may fill, each
+        applied range deletes first, then memtable writes, every op at
+        the seq its in-order application would have drawn: at every
+        flush the index holds exactly the range deletes issued before
+        it, and ``tree.seq`` reads what the in-order put batch had set.
+        """
+        tree = self.tree
+        io0 = tree.io.total
+        with span("shard.write_run", shard=sp.shard, batch=sp.seq,
+                  steps=len(run),
+                  puts=sum(len(s) for s in run if s.kind == OP_PUT),
+                  range_deletes=sum(len(s) for s in run
+                                    if s.kind == OP_RANGE_DELETE)):
+            at = 0
+            while at < len(run):
+                room = tree.config.buffer_capacity - len(tree.mem)
+                end, w = at, 0
+                while end < len(run):
+                    step = run[end]
+                    end += 1
+                    if step.kind != OP_RANGE_DELETE:
+                        w += len(step)
+                        if w >= room:
+                            break  # the memtable may fill in this step
+                self._write_segment(sp, run[at:end])
+                at = end
+        self.write_runs_fused += 1
+        self.write_steps_fused += len(run)
+        self._io_wait(io0)
+
+    def _write_segment(self, sp: ShardPlan, seg: list) -> None:
+        """One segment of a fused write run: its range deletes in one
+        call, then its memtable writes in one call per same-kind
+        stretch, at the seqs reserved for them in request order."""
+        at = self.tree.seq
+        own = []  # the seqs each step would draw in request order
+        for step in seg:
+            own.append(np.arange(at + 1, at + len(step) + 1,
+                                 dtype=np.uint64))
+            at += len(step)
+        rds = [i for i, s in enumerate(seg) if s.kind == OP_RANGE_DELETE]
+        mems = [i for i, s in enumerate(seg) if s.kind != OP_RANGE_DELETE]
+        with self.tree.reserved_seqs(
+                np.concatenate([own[i] for i in rds + mems]), at):
+            if rds:
+                n = sum(len(seg[i]) for i in rds)
+                with span("shard.range_delete", n=n, shard=sp.shard,
+                          batch=sp.seq):
+                    self.range_delete_arrays(
+                        np.concatenate([seg[i].los for i in rds]),
+                        np.concatenate([seg[i].his for i in rds]))
+            for kind, group in groupby((seg[i] for i in mems),
+                                       key=lambda s: s.kind):
+                group = list(group)
+                keys = np.concatenate([s.keys for s in group])
+                with span("shard." + KIND_NAMES[kind], n=len(keys),
+                          shard=sp.shard, batch=sp.seq):
+                    if kind == OP_PUT:
+                        self.put_batch(
+                            keys, np.concatenate([s.vals for s in group]))
+                    else:
+                        self.delete_batch(keys)
 
     # ------------------------------------------------------------ reads
     def _validity_fn(self):

@@ -18,6 +18,7 @@ cost model; benchmarks report those counts alongside wall time.
 from __future__ import annotations
 
 import threading
+from contextlib import contextmanager
 from dataclasses import dataclass
 from itertools import repeat
 
@@ -70,6 +71,9 @@ class LSMTree:
         self.levels: list[SSTable | None] = []
         self.level_rts: list[RangeTombstoneBlock] = []
         self.seq = 0
+        # Seqs handed out by ``_next_seqs`` inside ``reserved_seqs``.
+        self._reserved = None
+        self._reserved_at = 0
         self.gloran = None
         if strategy == "gloran":
             self.gloran = GloranIndex(gloran_config, io=self.io)
@@ -101,13 +105,37 @@ class LSMTree:
 
     # ------------------------------------------------------------ helpers
     def _next_seq(self) -> int:
+        if self._reserved is not None:
+            return int(self._next_seqs(1)[0])
         self.seq += 1
         return self.seq
 
     def _next_seqs(self, n: int) -> np.ndarray:
+        if self._reserved is not None:
+            at = self._reserved_at
+            out = self._reserved[at:at + n]
+            assert len(out) == n, "write past the reserved seqs"
+            self._reserved_at = at + n
+            return out
         out = np.arange(self.seq + 1, self.seq + n + 1, dtype=np.uint64)
         self.seq += n
         return out
+
+    @contextmanager
+    def reserved_seqs(self, seqs: np.ndarray, last: int):
+        """Serve ``seqs`` to the writes made inside, in call order.
+
+        A caller that applies writes out of request order (the engine's
+        fused write runs) reserves the seqs each write would have drawn
+        in order, and ``self.seq`` jumps to ``last`` up front: what an
+        in-order batch write has already done when its memtable flushes,
+        so a flush's GC watermark reads the same either way."""
+        self.seq = int(last)
+        self._reserved, self._reserved_at = seqs, 0
+        try:
+            yield
+        finally:
+            self._reserved = None
 
     def _mem_put(self, key: int, seq: int, typ: int, val: int) -> None:
         self.mem[int(key)] = (int(seq), int(typ), int(val))

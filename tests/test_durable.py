@@ -261,6 +261,47 @@ def test_recover_full_log_matches_original(tmp_path, strategy):
     rec.close()
 
 
+@pytest.mark.parametrize("shards", [1, 2, 4])
+def test_recover_over_fused_write_runs(tmp_path, shards):
+    """WriteBatches whose puts, deletes and range deletes interleave are
+    applied as fused write runs live; the WAL replays them step by
+    step, and the recovered store equals the live one."""
+    from repro.engine import OP_DELETE, OP_PUT, OP_RANGE_DELETE, OpBatch
+    wdir = tmp_path / "wal"
+    eng = make_engine(wdir, shards=shards)
+    rng = np.random.default_rng(17)
+    for _ in range(12):
+        n = 64
+        k = rng.choice(np.array([OP_PUT] * 6 + [OP_DELETE]
+                                + [OP_RANGE_DELETE] * 2, np.uint8), n)
+        keys = rng.integers(1, 4000, n).astype(np.uint64)
+        los = rng.integers(1, 3900, n).astype(np.uint64)
+        rd = k == OP_RANGE_DELETE
+        z = np.zeros(n, np.uint64)
+        eng.submit(OpBatch(k, keys=np.where(rd, z, keys),
+                           vals=np.where(k == OP_PUT, keys * np.uint64(3),
+                                         z),
+                           los=np.where(rd, los, z),
+                           his=np.where(rd, los + np.uint64(60), z))
+                   ).get_results()
+        eng.submit(OpBatch.gets(rng.integers(1, 4000, 100))).get_results()
+    assert eng.stats()["executor"]["write_runs_fused"] >= 12
+    live = [sh.tree.io.snapshot()["by_tag"] for sh in eng.shards]
+    assert all(t.get("index_flush") and t.get("compaction") for t in live)
+    eng.close()
+    rec = recover(str(wdir), config=EngineConfig(procs=0, devices=0,
+                                                 pipeline=False))
+    assert rec.stats()["executor"]["write_runs_fused"] == 0
+    assert_same_store(eng, rec)
+    for a, b in zip(eng.shards, rec.shards):
+        assert a.tree.gloran.gc_floor == b.tree.gloran.gc_floor
+        assert [len(l.areas) if l is not None else 0
+                for l in a.tree.gloran.index.levels] == \
+            [len(l.areas) if l is not None else 0
+             for l in b.tree.gloran.index.levels]
+    rec.close()
+
+
 def test_engine_refuses_dirty_wal_dir(tmp_path):
     eng = make_engine(tmp_path, shards=1)
     eng.put_batch(np.arange(1, 10, dtype=np.uint64),

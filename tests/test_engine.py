@@ -290,3 +290,130 @@ class TestEquivalence:
         f4, v4 = regs[1].lookup(sids, pages)
         np.testing.assert_array_equal(f1, f4)
         np.testing.assert_array_equal(v1[f1], v4[f4])
+
+
+# ------------------------------------------------------ fused write runs
+def _writebatch(rng, n, kinds, universe=2000):
+    """A WriteBatch of ``n`` ops drawn from ``kinds`` in shuffled order."""
+    from repro.engine import OP_PUT, OP_RANGE_DELETE, OpBatch
+    k = rng.choice(np.array(kinds, np.uint8), n)
+    keys = rng.integers(0, universe, n).astype(np.uint64)
+    los = rng.integers(0, universe - 50, n).astype(np.uint64)
+    rd = k == OP_RANGE_DELETE
+    z = np.zeros(n, np.uint64)
+    return OpBatch(k, keys=np.where(rd, z, keys),
+                   vals=np.where(k == OP_PUT, keys + np.uint64(1), z),
+                   los=np.where(rd, los, z),
+                   his=np.where(rd, los + np.uint64(50), z))
+
+
+def _fusion_engine(strategy, pipeline=False):
+    return Engine(num_shards=2, strategy=strategy,
+                  lsm_config=small_cfg(buffer_capacity=4096),
+                  gloran_config=small_gloran(index_buffer=4096)
+                  if strategy == "gloran" else None,
+                  config=EngineConfig(pipeline=pipeline, procs=0,
+                                      devices=0))
+
+
+class TestWriteRunFusion:
+    @pytest.mark.parametrize("strategy,kinds,fuses", [
+        ("gloran", "put+range_delete", True),
+        ("gloran", "put+delete+range_delete", True),
+        ("gloran", "delete+range_delete", True),
+        ("gloran", "put", False),
+        ("gloran", "range_delete", False),
+        ("gloran", "put+delete", False),
+        ("lrr", "put+delete+range_delete", False),
+        ("decomp", "put+delete+range_delete", False),
+        ("lookup_delete", "put+range_delete", False),
+        ("scan_delete", "put+range_delete", False),
+    ])
+    def test_engagement_rule(self, strategy, kinds, fuses):
+        """Only GLORAN write runs that mix range deletes with memtable
+        writes fuse, and the counters say how many runs and steps."""
+        from repro.engine import KIND_CODES
+        codes = [KIND_CODES[k] for k in kinds.split("+")]
+        eng = _fusion_engine(strategy)
+        rng = np.random.default_rng(3)
+        want_runs = want_steps = 0
+        for _ in range(3):
+            batch = _writebatch(rng, 120, codes)
+            for sp in eng.planner.plan(batch).shard_plans:
+                if len({s.kind for s in sp.steps}) > 1 and fuses:
+                    want_runs += 1
+                    want_steps += len(sp.steps)
+            eng.submit(batch).get_results()
+        ex = eng.stats()["executor"]
+        assert ex == {"write_runs_fused": want_runs,
+                      "write_steps_fused": want_steps}
+        assert (want_runs > 0) == fuses
+        assert eng.stats()["metrics"]["executor.write_runs_fused"] == \
+            want_runs
+
+    def test_write_run_span_holds_one_call_of_each_kind(self):
+        """A fused run opens ``shard.write_run`` with its step and op
+        counts, and inside it one ``shard.range_delete`` and one
+        ``shard.put`` span; the EVE insert stays under the former."""
+        from repro import obs
+        from repro.engine import OP_PUT, OP_RANGE_DELETE
+        eng = _fusion_engine("gloran", pipeline=True)
+        batch = _writebatch(np.random.default_rng(5), 200,
+                            [OP_PUT, OP_PUT, OP_RANGE_DELETE])
+        plans = eng.planner.plan(batch).shard_plans
+        with obs.enabled() as tr:
+            eng.submit(batch).get_results()
+        evs = tr.events()
+        runs = [e for e in evs if e["name"] == "shard.write_run"]
+        assert len(runs) == 2
+        for sp in plans:
+            run = next(e for e in runs if e["attrs"]["shard"] == sp.shard)
+            assert run["attrs"]["steps"] == len(sp.steps) > 2
+            assert run["attrs"]["puts"] == sum(
+                len(s) for s in sp.steps if s.kind == OP_PUT)
+            assert run["attrs"]["range_deletes"] == sum(
+                len(s) for s in sp.steps if s.kind == OP_RANGE_DELETE)
+            inside = [e for e in evs if e["tid"] == run["tid"]
+                      and run["t0"] <= e["t0"] and e["t1"] <= run["t1"]
+                      and e["name"].startswith("shard.")
+                      and e is not run]
+            assert sorted(e["name"] for e in inside) == \
+                ["shard.put", "shard.range_delete"]
+            rdel = next(e for e in inside
+                        if e["name"] == "shard.range_delete")
+            assert rdel["attrs"]["n"] == run["attrs"]["range_deletes"]
+            eve = [e for e in evs if e["name"] == "gloran.eve"
+                   and e["tid"] == run["tid"]]
+            assert len(eve) == 1
+            assert rdel["t0"] <= eve[0]["t0"] and eve[0]["t1"] <= rdel["t1"]
+
+    def test_timed_io_sleeps_once_per_fused_run(self, monkeypatch):
+        """In timed-I/O mode a fused run waits once, for the I/O its
+        steps charged together: the same total as step by step."""
+        from repro.engine import OP_DELETE, OP_PUT, OP_RANGE_DELETE
+        from repro.engine import executor as executor_mod
+        slept = {}
+
+        def run(fused):
+            calls = []
+            monkeypatch.setattr(executor_mod.time, "sleep", calls.append)
+            eng = Engine(num_shards=2, strategy="gloran",
+                         lsm_config=small_cfg(buffer_capacity=24),
+                         gloran_config=small_gloran(index_buffer=8),
+                         config=EngineConfig(pipeline=False, procs=0,
+                                             devices=0, io_wait_s=1e-6))
+            if not fused:
+                for sh in eng.shards:
+                    sh._fuses = lambda steps: False
+            rng = np.random.default_rng(9)
+            for _ in range(6):
+                eng.submit(_writebatch(rng, 80, [OP_PUT, OP_PUT, OP_DELETE,
+                                                 OP_RANGE_DELETE])
+                           ).get_results()
+            slept[fused] = calls
+            return eng.stats()["executor"]["write_runs_fused"]
+
+        assert run(True) == 12 and run(False) == 0
+        assert len(slept[True]) <= 12 < len(slept[False])
+        assert sum(slept[True]) == pytest.approx(sum(slept[False]))
+        assert sum(slept[True]) > 0

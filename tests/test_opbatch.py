@@ -5,10 +5,12 @@ malformed-batch validation."""
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import GloranConfig, LSMDRTreeConfig, RAEConfig
-from repro.engine import (OP_GET, OP_PUT, OP_RANGE_DELETE, OP_RANGE_SCAN,
-                          Engine, EngineConfig, OpBatch, Planner,
-                          ShardRouter)
+from repro.durable.manifest import structure_fingerprint
+from repro.engine import (OP_DELETE, OP_GET, OP_PUT, OP_RANGE_DELETE,
+                          OP_RANGE_SCAN, Engine, EngineConfig, OpBatch,
+                          Planner, ShardRouter)
 from repro.lsm import LSMConfig, STRATEGIES
 
 UNIVERSE = 1 << 20
@@ -378,3 +380,115 @@ class TestPipelinedParity:
             assert_results_identical(res[0], res[1])
             for eng in engines:
                 eng.flush()
+
+
+# -------------------------------------------------------- fused write runs
+def interleaved_writes(rng, n, universe=2000, max_len=40, reads=0.0):
+    """A batch of puts, point deletes and range deletes (and, with
+    ``reads``, that share of gets) in shuffled order, so each shard's
+    plan alternates short write steps of every kind."""
+    kinds = rng.choice(np.array([OP_PUT] * 10 + [OP_DELETE]
+                                + [OP_RANGE_DELETE] * 2, np.uint8), n)
+    kinds[rng.random(n) < reads] = OP_GET
+    keys = rng.integers(0, universe, n).astype(np.uint64)
+    los = rng.integers(0, universe - max_len, n).astype(np.uint64)
+    his = los + rng.integers(1, max_len, n).astype(np.uint64)
+    rd = kinds == OP_RANGE_DELETE
+    z = np.zeros(n, np.uint64)
+    return OpBatch(kinds, keys=np.where(rd, z, keys),
+                   vals=np.where(kinds == OP_PUT, keys * np.uint64(7) + 1, z),
+                   los=np.where(rd, los, z), his=np.where(rd, his, z))
+
+
+def fusion_engine(strategy, shards, scheduler):
+    """Tiny memtable, index buffer and EVE filters, so memtable flushes,
+    bottom compactions, index flushes and GC land inside write runs."""
+    g = GloranConfig(index=LSMDRTreeConfig(buffer_capacity=8, size_ratio=3,
+                                           key_size=16, block_size=512),
+                     eve=RAEConfig(capacity=16, key_universe=UNIVERSE))
+    return Engine(num_shards=shards, strategy=strategy,
+                  lsm_config=small_cfg(buffer_capacity=24),
+                  gloran_config=g if strategy == "gloran" else None,
+                  config=EngineConfig(pipeline=False, procs=0, devices=0,
+                                      scheduler=scheduler))
+
+
+def shard_state(sh) -> dict:
+    """Everything a write run can leave behind on one shard."""
+    t = sh.tree
+    cols = ("keys", "seqs", "types", "vals")
+    state = {
+        "seq": t.seq, "io": t.io.snapshot(), "mem": dict(t.mem),
+        "levels": [None if lvl is None else
+                   [getattr(lvl, c).tobytes() for c in cols]
+                   for lvl in t.levels],
+        "frozen": [[getattr(f, c).tobytes() for c in cols]
+                   for f in t.frozen],
+        "index_epoch": structure_fingerprint(t)[1]}
+    g = t.gloran
+    if g is not None:
+        state["gc_floor"] = g.gc_floor
+        state["eve"] = [(r.count, r.min_seq, r.max_seq,
+                         r.bloom.words.tobytes()) for r in g.eve.chain]
+        state["index"] = [None if lvl is None else
+                          [getattr(lvl.areas, c).tobytes()
+                           for c in ("lo", "hi", "smin", "smax")]
+                          for lvl in g.index.levels]
+        state["staged"] = g.index.buffer.extract_all().lo.tobytes()
+    return state
+
+
+def _drive_fusion(eng, seed):
+    """Interleaved WriteBatches, each followed by a MultiGet, and every
+    fourth round a mixed request with hoisted reads; per request its
+    answers and whether each shard's level structure moved."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for r in range(16):
+        batches = [interleaved_writes(rng, 64),
+                   OpBatch.gets(rng.integers(0, 2000, 150))]
+        if r % 4 == 3:
+            batches.append(interleaved_writes(rng, 96, reads=0.3))
+        for b in batches:
+            fp0 = [structure_fingerprint(sh.tree) for sh in eng.shards]
+            found, vals = eng.submit(b).get_results()
+            moved = [structure_fingerprint(sh.tree) != f
+                     for sh, f in zip(eng.shards, fp0)]
+            out.append((found.tobytes(), vals[found].tobytes(), moved))
+    eng.drain()
+    return out
+
+
+@pytest.mark.parametrize("scheduler", [False, True])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+@pytest.mark.parametrize("strategy", ["gloran", "lrr", "decomp"])
+def test_fused_write_runs_match_step_by_step(strategy, shards, scheduler):
+    """A fused write run leaves answers, I/O ledgers, level shapes and
+    contents, seqs, the GC floor and the EVE chain exactly as applying
+    its plan steps one by one does; only GLORAN trees fuse."""
+    fused = fusion_engine(strategy, shards, scheduler)
+    ref = fusion_engine(strategy, shards, scheduler)
+    for sh in ref.shards:
+        sh._fuses = lambda run: False
+    with obs.enabled() as tr:
+        got = _drive_fusion(fused, seed=100 + shards)
+    want = _drive_fusion(ref, seed=100 + shards)
+    assert got == want
+    for a, b in zip(fused.shards, ref.shards):
+        assert shard_state(a) == shard_state(b)
+    n_fused = fused.stats()["executor"]["write_runs_fused"]
+    assert ref.stats()["executor"]["write_runs_fused"] == 0
+    if strategy != "gloran":
+        assert n_fused == 0
+        return
+    assert n_fused > 0
+    assert all(sh.tree.gloran.gc_floor > 0 for sh in fused.shards)
+    # Memtable flushes (seals with the scheduler on) and index flushes
+    # happened inside fused runs, not only between them.
+    evs = tr.events()
+    runs = [e for e in evs if e["name"] == "shard.write_run"]
+    for name in ("lsm.seal" if scheduler else "lsm.flush",
+                 "gloran.index_flush"):
+        assert any(r["tid"] == e["tid"] and r["t0"] <= e["t0"]
+                   and e["t1"] <= r["t1"]
+                   for e in evs if e["name"] == name for r in runs), name
