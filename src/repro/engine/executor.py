@@ -52,6 +52,7 @@ import numpy as np
 from ..core.eve import fold64to32
 from ..kernels.bloom.ops import bloom_probe
 from ..kernels.cascade.ops import cascade_lookup
+from ..kernels.dispatch import kernel_form
 from ..kernels.interval.ops import interval_query
 from ..kernels.merge.ops import merge_ranks
 from ..lsm.tree import CascadeVerdict, LSMTree
@@ -523,15 +524,18 @@ class ShardExecutor:
 
         Gates: the batch must be worth a launch (``kernel_min_batch``),
         the tree's packed view must exist (non-empty levels, u32-exact
-        keys/seqs, within the VMEM pack budgets — see
-        ``DeviceFilterRegistry``), and the query keys plus any
-        memtable-resolved seqs must fit u32 working space.  A declined
-        launch falls back to the per-level path with identical results.
+        keys/seqs, within the pack budget of the cascade's form on this
+        shard's device — see ``DeviceFilterRegistry``), and the query
+        keys plus any memtable-resolved seqs must fit u32 working space.
+        A declined launch falls back to the per-level path with identical
+        results.
         """
         cfg = self.config
         if not cfg.use_cascade_kernel or len(keys) < cfg.kernel_min_batch:
             return None
-        view = self.registry.view(self.tree)
+        form = kernel_form("cascade", interpret=cfg.interpret,
+                           compiled=cfg.cascade_compiled)
+        view = self.registry.view(self.tree, form)
         if view is None:
             return None
         if int(keys.max()) >= _U32_LIMIT:

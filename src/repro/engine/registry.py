@@ -22,13 +22,17 @@ Eligibility: the cascade compares keys exactly in u32 working space
 seqs reach 2^32 - 1 is declined wholesale and the per-level host/kernel
 path serves it — identical results, just per-level launches.  GLORAN
 interval columns are *clamped* into u32 like the per-level view (exact
-for u32-range queries); packs past the kernels' VMEM budgets are also
-declined.  Every decline is cached on the same key as a hit, so
-ineligible trees pay one scan, not one per lookup, and is counted once
-per structure by reason in the kernel counters: ``pack_declined_u32``
-(keys or seqs past u32), ``pack_declined_keys`` (past
-``MAX_PACK_KEYS``), ``pack_declined_bytes`` (past the word, area or
-total byte budget).
+for u32-range queries).  Packs past the budget of the cascade's form on
+the registry's home device (``kernels.cascade.ops.pack_budget``) are
+also declined: the VMEM limits for the Pallas form, a share of the
+device's memory for the XLA form, which reads its operands from HBM.
+Every decline is cached on the same key as a hit, so ineligible trees
+pay one scan, not one per lookup, and is counted once per structure by
+reason in the kernel counters: ``pack_declined_u32`` (keys or seqs past
+u32), ``pack_declined_keys`` (key slots past the budget's),
+``pack_declined_bytes`` (past the word, area or total byte budget).
+The resident bytes of each device's packs are a gauge,
+``pack_bytes_by_device``, set at every build.
 """
 
 from __future__ import annotations
@@ -41,9 +45,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.cascade.ops import (CascadeState, MAX_PACK_AREAS,
-                                   MAX_PACK_BYTES, MAX_PACK_KEYS,
-                                   MAX_PACK_WORDS, pack_bytes)
+from ..kernels.cascade.ops import (CascadeState, device_key, pack_budget,
+                                   pack_bytes)
+from ..kernels.dispatch import kernel_form
 from ..obs import span
 from .stats import KernelCounters
 
@@ -152,8 +156,7 @@ class DeviceFilterRegistry:
         # placement) — per-device jit, no cross-shard serialization on
         # device 0.
         self.device = device
-        self._dev_key = "host" if device is None else \
-            f"{device.platform}:{device.id}"
+        self._dev_key = device_key(device)
         # Caches key on (uid-or-identity, device) per the invalidation
         # contract: a piece is only reusable on the device it was
         # committed to.  A registry serves one shard = one device, so
@@ -181,27 +184,34 @@ class DeviceFilterRegistry:
         by_dev[self._dev_key] = by_dev.get(self._dev_key, 0) + nbytes
 
     # ----------------------------------------------------------- packing
-    def view(self, tree) -> CascadeView | None:
+    def view(self, tree, form: str | None = None) -> CascadeView | None:
         """The cascade view of ``tree``'s current levels (+ GLORAN index
         when present), rebuilt only when the structure moved; None when
-        the tree is cascade-ineligible."""
+        the tree is cascade-ineligible.  ``form`` is the kernel form the
+        cascade will run in (None: the backend's default), which sets
+        the pack budget."""
+        form = form or kernel_form("cascade")
         lvls = [(i, lvl) for i, lvl in enumerate(tree.levels)
                 if lvl is not None and len(lvl)]
         gloran = tree.gloran if tree.strategy == "gloran" else None
         gl_levels = gloran.level_views() if gloran is not None else None
-        key = (len(tree.levels),
+        key = (form, len(tree.levels),
                tuple((i, lvl.uid, len(lvl)) for i, lvl in lvls),
                None if gloran is None else gloran.index_epoch,
                None if gl_levels is None else len(gl_levels))
         if key == self._view_key:
             return self._view
         with span("registry.pack", levels=len(lvls),
-                  gl_levels=len(gl_levels or [])):
-            view = self._build(tree, lvls, gl_levels)
+                  gl_levels=len(gl_levels or []),
+                  device=self._dev_key) as sp:
+            view = self._build(tree, lvls, gl_levels, form)
+            nbytes = 0 if view is None else view.state.nbytes
+            sp.set(bytes=nbytes)
+        self.counters.pack_bytes_by_device[self._dev_key] = nbytes
         self._view, self._view_key = view, key
         return view
 
-    def _build(self, tree, lvls, gl_levels) -> CascadeView | None:
+    def _build(self, tree, lvls, gl_levels, form) -> CascadeView | None:
         # Evict first, gate after: even a tree that has become cascade-
         # ineligible must release the pieces (and the runs/levels they
         # pin) of structures compaction has since replaced.
@@ -227,12 +237,13 @@ class DeviceFilterRegistry:
         # bound is conservative (a decline just means per-level serving).
         area_slots = sum(max(64, _next_pow2(len(g.areas)))
                          for g in (gl_levels or []))
-        if key_slots > MAX_PACK_KEYS:
+        budget = pack_budget(form, self.device)
+        if key_slots > budget.keys:
             self.counters.pack_declined_keys += 1
             return None
-        if (word_slots > MAX_PACK_WORDS or area_slots > MAX_PACK_AREAS
+        if (word_slots > budget.words or area_slots > budget.areas
                 or pack_bytes(key_slots, word_slots,
-                              area_slots) > MAX_PACK_BYTES):
+                              area_slots) > budget.bytes):
             self.counters.pack_declined_bytes += 1
             return None
         pieces = [self._run_piece(lvl) for _, lvl in lvls]

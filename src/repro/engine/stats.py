@@ -27,8 +27,8 @@ class KernelCounters:
     cascade_queries: int = 0    # lookups answered by the cascade
     cascade_packs: int = 0      # registry device-state (re)packs
     # Packs the registry declined, once per new tree structure, by
-    # reason: keys/seqs past u32, key slots past MAX_PACK_KEYS, or the
-    # word/area/total byte budgets.
+    # reason: keys/seqs past u32, key slots past the pack budget's, or
+    # the word/area/total byte budgets (``cascade.ops.pack_budget``).
     pack_declined_u32: int = 0
     pack_declined_keys: int = 0
     pack_declined_bytes: int = 0
@@ -38,6 +38,10 @@ class KernelCounters:
     # multi-device registry charges, so steady-state "uploaded once per
     # device, not once per batch" is assertable per device.
     upload_bytes_by_device: dict = field(default_factory=dict)
+    # A gauge, not a ledger: each home device's resident cascade pack
+    # bytes (``pack_bytes``), set at every registry build (0 when the
+    # pack is declined); fleet rollups sum the shards sharing a device.
+    pack_bytes_by_device: dict = field(default_factory=dict)
 
     def merge(self, other: "KernelCounters") -> None:
         """Accumulate another ledger into this one (fleet rollups)."""
@@ -54,9 +58,11 @@ class KernelCounters:
         self.pack_declined_keys += other.pack_declined_keys
         self.pack_declined_bytes += other.pack_declined_bytes
         self.upload_bytes += other.upload_bytes
-        for dev, nbytes in other.upload_bytes_by_device.items():
-            self.upload_bytes_by_device[dev] = \
-                self.upload_bytes_by_device.get(dev, 0) + nbytes
+        for mine, theirs in (
+                (self.upload_bytes_by_device, other.upload_bytes_by_device),
+                (self.pack_bytes_by_device, other.pack_bytes_by_device)):
+            for dev, nbytes in theirs.items():
+                mine[dev] = mine.get(dev, 0) + nbytes
 
     @classmethod
     def from_snapshot(cls, snap: dict) -> "KernelCounters":
@@ -64,9 +70,8 @@ class KernelCounters:
         cumulative kernel ledger rehydrates on the parent side."""
         out = cls()
         for k, v in (snap or {}).items():
-            if k == "upload_bytes_by_device":
-                out.upload_bytes_by_device = {str(d): int(b)
-                                              for d, b in v.items()}
+            if k in ("upload_bytes_by_device", "pack_bytes_by_device"):
+                setattr(out, k, {str(d): int(b) for d, b in v.items()})
             elif hasattr(out, k):
                 setattr(out, k, int(v))
         return out
@@ -88,6 +93,8 @@ class KernelCounters:
             "upload_bytes": self.upload_bytes,
             "upload_bytes_by_device": dict(sorted(
                 self.upload_bytes_by_device.items())),
+            "pack_bytes_by_device": dict(sorted(
+                self.pack_bytes_by_device.items())),
         }
 
 

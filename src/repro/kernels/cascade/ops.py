@@ -9,9 +9,13 @@ either the jit'd pure-XLA form of the math (the default, and the only
 form on a TPU backend — see ``kernels.dispatch``) or, with
 ``compiled=False`` off the chip, the Pallas kernel in interpret mode.
 
-VMEM budget: packs whose key/word/area totals exceed the ``MAX_PACK_*``
-limits are left to the per-level chunked kernels (the registry declines
-to build them), keeping every launch's resident state under VMEM.
+Pack budget (``pack_budget``): the registry declines a pack past the
+budget of the form that will run it and the device that will hold it,
+and the per-level kernels serve that tree instead.  The Pallas form
+holds its whole pack in VMEM, so it keeps the ``MAX_PACK_*`` limits.
+The XLA form reads its operands from HBM: a pack may take a fixed share
+of its home device's memory (``bytes_limit`` of ``memory_stats()``),
+or ``HOST_PACK_BYTES`` where the device reports no limit (the CPU).
 """
 
 from __future__ import annotations
@@ -23,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...obs import span
-from ..dispatch import XLA, kernel_form
+from ..dispatch import PALLAS, XLA, kernel_form
 from .kernel import LANES, cascade_pallas
 from .ref import cascade_flat
 
@@ -33,6 +37,9 @@ def pack_bytes(key_slots: int, word_slots: int, area_slots: int) -> int:
     four u32 interval columns (one budget formula for gate + docs)."""
     return 8 * key_slots + 4 * word_slots + 16 * area_slots
 
+
+# VMEM limits of the Pallas form, which holds a launch's whole pack in
+# VMEM.
 MAX_PACK_KEYS = 1 << 20  # u32 keys+seqs: 8 MB resident
 MAX_PACK_WORDS = 1 << 20  # 4 MB of packed filter words
 MAX_PACK_AREAS = 1 << 20  # 4 arrays x 4 B x 1 Mi = 16 MB / 4
@@ -42,6 +49,44 @@ MAX_PACK_AREAS = 1 << 20  # 4 arrays x 4 B x 1 Mi = 16 MB / 4
 # keys+seqs (8 B/slot) + words (4 B) + interval columns (16 B/area)
 # exceed this, so the sum stays under VMEM with tile/output headroom.
 MAX_PACK_BYTES = 12 << 20
+
+# The XLA form's share of its home device's memory, per pack.  While a
+# pack is rebuilt its pieces, its concatenation and the view it replaces
+# are resident together (three copies), and up to four shards may share
+# one device: twelve sixteenths, with the rest left to the kernels'
+# working set and the program's other arrays.
+HBM_PACK_SHARE = 1 / 16
+# The XLA form's budget on a device that reports no memory limit.
+HOST_PACK_BYTES = 1 << 30
+
+
+@dataclass(frozen=True)
+class PackBudget:
+    """Largest admissible pack: slots of each operand and total bytes
+    (by ``pack_bytes``)."""
+
+    keys: int
+    words: int
+    areas: int
+    bytes: int
+
+
+def pack_budget(form: str, device=None) -> PackBudget:
+    """The pack budget of a cascade that runs in ``form`` on ``device``
+    (None: JAX's default device).
+
+    The Pallas form keeps the VMEM limits.  The XLA form may take
+    ``HBM_PACK_SHARE`` of the device's ``bytes_limit``, or
+    ``HOST_PACK_BYTES`` where ``memory_stats()`` reports none; each
+    operand alone may then fill the whole budget."""
+    if form == PALLAS:
+        return PackBudget(MAX_PACK_KEYS, MAX_PACK_WORDS, MAX_PACK_AREAS,
+                          MAX_PACK_BYTES)
+    dev = device if device is not None else jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    total = HOST_PACK_BYTES if not limit else int(limit * HBM_PACK_SHARE)
+    return PackBudget(keys=total // 8, words=total // 4, areas=total // 16,
+                      bytes=total)
 
 
 @dataclass
@@ -78,6 +123,18 @@ class CascadeState:
     key_sizes: tuple = ()
     gl_sizes: tuple = ()
 
+    @property
+    def nbytes(self) -> int:
+        """Resident operand bytes, by ``pack_bytes``."""
+        return pack_bytes(sum(self.key_pad), sum(self.word_pad),
+                          sum(self.gl_pad))
+
+
+def device_key(device) -> str:
+    """``platform:id`` of a home device, or ``"host"`` for None (the
+    default device): the name spans and per-device counters use."""
+    return "host" if device is None else f"{device.platform}:{device.id}"
+
 
 _cascade_xla = jax.jit(cascade_flat, static_argnames=(
     "L", "H", "G", "key_pad", "word_pad", "gl_pad"))
@@ -107,7 +164,8 @@ def cascade_lookup(qkey32, qhash32, qseq32, qres, state: CascadeState, *,
     """
     with span("kernel.cascade", n=len(qkey32), levels=state.L,
               gl_levels=state.G, key_cnt=state.key_sizes,
-              gl_cnt=state.gl_sizes, hashes=state.H):
+              gl_cnt=state.gl_sizes, hashes=state.H,
+              device=device_key(device)):
         return _cascade_lookup(qkey32, qhash32, qseq32, qres, state,
                                block_rows=block_rows, interpret=interpret,
                                compiled=compiled, device=device)

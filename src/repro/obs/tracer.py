@@ -46,6 +46,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **attrs) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -107,6 +110,10 @@ class _Span:
             self.c0 = _thread_time_ns()
         self.t0 = _perf_counter()
         return self
+
+    def set(self, **attrs) -> None:
+        """Add attrs known only once the span's work is done."""
+        self.attrs.update(attrs)
 
     def __exit__(self, *exc) -> bool:
         t1 = _perf_counter()

@@ -351,11 +351,12 @@ def test_engine_cpu_time_on_planner_spans_only():
 
 
 def test_declined_packs_counted_once_by_reason(monkeypatch):
-    """Past ``MAX_PACK_KEYS`` the registry declines the pack: the decline
-    is counted once per new structure, shows in ``stats()["metrics"]``,
-    and the per-level path still answers exactly."""
-    from repro.engine import registry
-    monkeypatch.setattr(registry, "MAX_PACK_KEYS", 64)
+    """Past the pack budget's key slots the registry declines the pack:
+    the decline is counted once per new structure, shows in
+    ``stats()["metrics"]``, and the per-level path still answers
+    exactly."""
+    from repro.kernels.cascade import ops
+    monkeypatch.setattr(ops, "HOST_PACK_BYTES", 64)
     eng = _gloran_engine()
     model: dict = {}
     rng = np.random.default_rng(5)

@@ -70,9 +70,9 @@ def _compile(fn, *args, **static):
     return fn.lower(*args, **static).compile()
 
 
-def test_cascade_compiles_for_v5e(shape):
-    L, G = len(KEY_PAD), len(GL_PAD)
-    K, W, A = sum(KEY_PAD), sum(WORD_PAD), sum(GL_PAD)
+def _compile_cascade(shape, key_pad, word_pad, gl_pad):
+    L, G = len(key_pad), len(gl_pad)
+    K, W, A = sum(key_pad), sum(word_pad), sum(gl_pad)
     i32 = jnp.int32
     q = shape((QUERIES,))
     _compile(cascade_ops._cascade_xla, q, q, q, shape((QUERIES,), i32),
@@ -80,7 +80,21 @@ def test_cascade_compiles_for_v5e(shape):
              shape((W,)), shape((L,), i32), shape((L,)), shape((L, H)),
              shape((A,)), shape((A,)), shape((A,)), shape((A,)),
              shape((G,), i32), shape((G,), i32), L=L, H=H, G=G,
-             key_pad=KEY_PAD, word_pad=WORD_PAD, gl_pad=GL_PAD)
+             key_pad=key_pad, word_pad=word_pad, gl_pad=gl_pad)
+
+
+def test_cascade_compiles_for_v5e(shape):
+    _compile_cascade(shape, KEY_PAD, WORD_PAD, GL_PAD)
+
+
+def test_cascade_compiles_for_v5e_past_the_vmem_caps(shape):
+    """One shard of 2,621,440 keys, as each chip of the four-chip
+    deployment holds: a 2^22-slot level beside two smaller ones, ~4.8M
+    key slots and ~43 MB, past the Pallas form's VMEM limits."""
+    key_pad = (1 << 16, 1 << 19, 1 << 22)
+    assert sum(key_pad) > cascade_ops.MAX_PACK_KEYS
+    _compile_cascade(shape, key_pad, (1 << 12, 1 << 15, 1 << 20),
+                     (1 << 13,))
 
 
 def test_bloom_compiles_for_v5e(shape):
