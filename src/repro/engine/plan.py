@@ -292,10 +292,12 @@ class Planner:
     def plan(self, batch: OpBatch) -> Plan:
         seq = next(self._seq)
         with span("plan.compile", kind=batch.kind_name,
-                  n_ops=len(batch), batch=seq):
-            return self._plan(batch, seq)
+                  n_ops=len(batch), batch=seq) as sp:
+            plan, slots, conflicts = self._plan(batch, seq)
+            sp.set(read_slots=slots, read_conflicts=conflicts)
+            return plan
 
-    def _plan(self, batch: OpBatch, seq: int) -> Plan:
+    def _plan(self, batch: OpBatch, seq: int) -> tuple[Plan, int, int]:
         ns = self.router.num_shards
         kinds = batch.kinds
         point_ids = np.flatnonzero(kinds <= OP_GET)
@@ -312,6 +314,7 @@ class Planner:
             batch.los[range_ids], batch.his[range_ids])
 
         plans = []
+        slots = conflicts = 0
         for s in range(ns):
             oidx = point_ids[psplit[s]]
             slo = shi = None
@@ -327,136 +330,172 @@ class Planner:
                      chis[vm]])
                 order = np.argsort(oidx, kind="stable")
                 oidx, slo, shi = oidx[order], slo[order], shi[order]
-            plans.append(self._shard_plan(s, batch, oidx, slo, shi))
-        for sp in plans:
+            sp, n_slots, n_conflicts = self._shard_plan(s, batch, oidx,
+                                                        slo, shi)
             sp.seq = seq
-        return Plan(batch=batch, shard_plans=plans, seq=seq)
+            plans.append(sp)
+            slots += n_slots
+            conflicts += n_conflicts
+        return Plan(batch=batch, shard_plans=plans, seq=seq), slots, conflicts
 
     def _shard_plan(self, s: int, batch: OpBatch, oidx: np.ndarray,
-                    slo, shi) -> ShardPlan:
-        """Split one shard's ordered op-id stream into vectorized steps.
+                    slo, shi) -> tuple[ShardPlan, int, int]:
+        """Split one shard's ordered op-id stream into vectorized steps;
+        returns the plan, its read slots, and the reads that closed one.
 
         Writes split on every kind change (their relative order is the
         semantics).  Reads are scheduled dependency-aware: a get or a
         range scan commutes with every other read, and it commutes with
         an intervening *write* as long as the write does not touch its
         key(s) — a range delete over a cold slab cannot change what a
-        hot get observes.  The planner therefore keeps one *open read
-        slot* and hoists each arriving read into it unless the read
-        overlaps a write accumulated since the slot opened; a
-        conflicting read closes the slot (materializing at most one
-        batched-get step and one batched-scan step at its position) and
-        opens a fresh slot after the writes.  Mixed streams thus compile
-        to a few large read sub-batches — big enough to amortize kernel
-        launches — while every read still observes exactly the writes
-        its results depend on.
+        hot get observes.  Number the stream's same-kind segments; for
+        each read, ``last`` is the last write segment before its own
+        that touches it.  A *read slot* opens at the first read segment
+        and holds every read whose ``last`` precedes the slot; the first
+        read with a later ``last`` closes it, and the next slot opens at
+        that read's segment.  Each slot executes where it opened — one
+        batched-get step, then one batched-scan step, both in op-id
+        order — so mixed streams compile to a few large read sub-batches
+        while every read still observes exactly the writes its results
+        depend on.
         """
         sp = ShardPlan(shard=s)
-        if len(oidx) == 0:
-            return sp
+        n = len(oidx)
+        if n == 0:
+            return sp, 0, 0
         k = batch.kinds[oidx]
         wr = (k != OP_GET) & (k != OP_RANGE_SCAN)
         brk = (wr[1:] != wr[:-1]) | (wr[1:] & (k[1:] != k[:-1]))
-        bounds = np.concatenate(
-            [[0], np.flatnonzero(brk) + 1, [len(k)]])
+        starts = np.concatenate([[0], np.flatnonzero(brk) + 1])
+        keys = batch.keys[oidx]
+        vals = batch.vals[oidx]
 
-        items: list = []  # PlanStep (writes) | dict (open read slots)
-        slot: dict | None = None
+        rpos = np.flatnonzero(~wr)  # reads, in op-id order
+        opens: list = []  # the segment each read slot opens at
+        conflicts = 0
+        if len(rpos) == n:
+            opens = [0]  # reads only: one slot, nothing to test
+            groups = [rpos]
+        elif len(rpos):
+            seg = np.concatenate([[0], np.cumsum(brk)])
+            rseg = seg[rpos]
+            last = self._last_writes(k, seg, keys, slo, shi, rpos, rseg)
+            # Slot chase: a read conflicts with the slot opened at segment
+            # o iff last > o, so the next slot opens at the first segment
+            # holding such a read — a suffix minimum over reads by last.
+            by_last = np.argsort(last, kind="stable")
+            last_sorted = last[by_last]
+            first_seg = np.minimum.accumulate(rseg[by_last][::-1])[::-1]
+            opens = [int(rseg[0])]
+            while True:
+                i = int(np.searchsorted(last_sorted, opens[-1], "right"))
+                if i == len(last_sorted):
+                    break
+                opens.append(int(first_seg[i]))
+            # Each read joins the newest slot opened at or before its
+            # segment, unless that slot opened at its own segment and
+            # the read does not conflict with the slot before.
+            op_arr = np.asarray(opens)
+            slot = np.searchsorted(op_arr, rseg, "right") - 1
+            at_open = (slot > 0) & (op_arr[slot] == rseg)
+            back = at_open & (last <= op_arr[np.maximum(slot - 1, 0)])
+            slot -= back
+            conflicts = int((at_open & ~back).sum())
+            by_slot = np.argsort(slot, kind="stable")
+            cuts = np.searchsorted(slot[by_slot], np.arange(1, len(opens)))
+            groups = np.split(rpos[by_slot], cuts)
 
-        def open_slot() -> dict:
-            # gets/scans accumulate op ids (+ scan bounds); wlo/whi and
-            # wkeys are the ranges/keys written since the slot opened.
-            s_ = {"gets": [], "scans": [], "wlo": [], "whi": [],
-                  "wkeys": []}
-            items.append(s_)
-            return s_
+        def emit_slot(j: int) -> None:
+            in_slot = groups[j]
+            rk = k[in_slot]
+            gpos = in_slot[rk == OP_GET]
+            if len(gpos):
+                sp.steps.append(PlanStep(kind=OP_GET, idx=oidx[gpos],
+                                         keys=keys[gpos]))
+            spos = in_slot[rk == OP_RANGE_SCAN]
+            if len(spos):
+                sp.steps.append(PlanStep(kind=OP_RANGE_SCAN,
+                                         idx=oidx[spos], los=slo[spos],
+                                         his=shi[spos]))
 
-        for a, b in zip(bounds[:-1].tolist(), bounds[1:].tolist()):
-            kind = int(k[a])
-            idx = oidx[a:b]
-            if wr[a]:
-                if kind in _POINT_KINDS:
-                    items.append(PlanStep(
-                        kind=kind, idx=idx, keys=batch.keys[idx],
-                        vals=batch.vals[idx] if kind == OP_PUT else None))
-                    if slot is not None:
-                        slot["wkeys"].append(batch.keys[idx])
-                else:
-                    items.append(PlanStep(
-                        kind=kind, idx=idx, los=slo[a:b], his=shi[a:b]))
-                    if slot is not None:
-                        slot["wlo"].append(slo[a:b])
-                        slot["whi"].append(shi[a:b])
-                continue
-            if slot is None:
-                slot = open_slot()
-            gets = idx[k[a:b] == OP_GET]
-            sm = k[a:b] == OP_RANGE_SCAN
-            scans = (idx[sm], slo[a:b][sm], shi[a:b][sm]) \
-                if sm.any() else None
-            g_conf, s_conf = self._read_conflicts(batch, slot, gets,
-                                                  scans)
-            if len(gets):
-                slot["gets"].append(gets[~g_conf])
-            if scans is not None:
-                slot["scans"].append(tuple(x[~s_conf] for x in scans))
-            if g_conf.any() or (s_conf is not None and s_conf.any()):
-                # Conflicting reads must observe the writes: close the
-                # slot and start a fresh one after them.
-                slot = open_slot()
-                if g_conf.any():
-                    slot["gets"].append(gets[g_conf])
-                if s_conf is not None and s_conf.any():
-                    slot["scans"].append(tuple(x[s_conf] for x in scans))
-
-        for item in items:
-            if isinstance(item, PlanStep):
-                sp.steps.append(item)
-                continue
-            gids = [g for g in item["gets"] if len(g)]
-            if gids:
-                gid = np.concatenate(gids)
-                sp.steps.append(PlanStep(kind=OP_GET, idx=gid,
-                                         keys=batch.keys[gid]))
-            sids = [t for t in item["scans"] if len(t[0])]
-            if sids:
+        # Visit the write segments and the segments slots open at.
+        visit = wr[starts]
+        visit[opens] = True
+        g = np.flatnonzero(visit)
+        ends = np.append(starts[1:], n)
+        slot_at = {o: j for j, o in enumerate(opens)}
+        for at, a, b, kind in zip(g.tolist(), starts[g].tolist(),
+                                  ends[g].tolist(), k[starts[g]].tolist()):
+            if kind == OP_RANGE_DELETE:
+                sp.steps.append(PlanStep(kind=kind, idx=oidx[a:b],
+                                         los=slo[a:b], his=shi[a:b]))
+            elif kind == OP_PUT or kind == OP_DELETE:
                 sp.steps.append(PlanStep(
-                    kind=OP_RANGE_SCAN,
-                    idx=np.concatenate([t[0] for t in sids]),
-                    los=np.concatenate([t[1] for t in sids]),
-                    his=np.concatenate([t[2] for t in sids])))
-        return sp
+                    kind=kind, idx=oidx[a:b], keys=keys[a:b],
+                    vals=vals[a:b] if kind == OP_PUT else None))
+            else:
+                emit_slot(slot_at[at])
+        return sp, len(opens), conflicts
 
     @staticmethod
-    def _read_conflicts(batch: OpBatch, slot: dict, gets: np.ndarray,
-                        scans):
-        """Which of a read segment's ops overlap the slot's writes.
+    def _last_writes(k, seg, keys, slo, shi, rpos, rseg) -> np.ndarray:
+        """For each read, the last write segment before its own that
+        touches it, or -1.  A get is touched by a write range covering
+        its key or a written key equal to it; a scan by a write range
+        overlapping [lo, hi) or a written key inside it."""
+        last = np.full(len(rpos), -1, np.int64)
+        pw = np.flatnonzero((k == OP_PUT) | (k == OP_DELETE))
+        rw = np.flatnonzero(k == OP_RANGE_DELETE)
+        if len(pw) + len(rw) == 0:
+            return last
+        is_get = k[rpos] == OP_GET
+        gi = np.flatnonzero(is_get)
+        if len(pw) and len(gi):
+            # Gets against point writes: sort by (key, position) and
+            # carry the last write within each key group.
+            pos = np.concatenate([pw, rpos[gi]])
+            order = np.lexsort((pos, keys[pos]))
+            wmark = np.where(order < len(pw), np.arange(len(pos)), -1)
+            prev = np.maximum.accumulate(wmark)
+            sk = keys[pos[order]]
+            at = np.flatnonzero(order >= len(pw))
+            m = prev[at]
+            hit = (m >= 0) & (sk[np.maximum(m, 0)] == sk[at])
+            last[gi[order[at[hit]] - len(pw)]] = seg[pos[order[m[hit]]]]
+        # Interval tests as inclusive [lo, hi] bounds: a get is [key, key],
+        # a scan or range write [lo, hi - 1], a point write [key, key].
+        if len(rw) and len(gi):
+            gk = keys[rpos[gi]]
+            last[gi] = np.maximum(last[gi], _last_overlap(
+                gk, gk, rseg[gi], slo[rw], shi[rw] - 1, seg[rw]))
+        si = np.flatnonzero(~is_get)
+        if len(si):  # scans: slo/shi exist, so rw may index them
+            sp_ = rpos[si]
+            last[si] = _last_overlap(
+                slo[sp_], shi[sp_] - 1, rseg[si],
+                np.concatenate([keys[pw], slo[rw]]),
+                np.concatenate([keys[pw], shi[rw] - 1]),
+                seg[np.concatenate([pw, rw])])
+        return last
 
-        A get conflicts if a write range covers its key or a written key
-        equals it; a scan conflicts if a write range overlaps [lo, hi)
-        or a written key falls inside it.  Everything else is safe to
-        hoist into the open slot (the writes cannot change its result).
-        """
-        wlo = np.concatenate(slot["wlo"]) if slot["wlo"] else None
-        wk = np.concatenate(slot["wkeys"]) if slot["wkeys"] else None
-        g_conf = np.zeros(len(gets), dtype=bool)
-        if len(gets):
-            keys = batch.keys[gets]
-            if wlo is not None:
-                whi = np.concatenate(slot["whi"])
-                g_conf |= ((keys[:, None] >= wlo[None, :]) &
-                           (keys[:, None] < whi[None, :])).any(axis=1)
-            if wk is not None:
-                g_conf |= np.isin(keys, wk)
-        if scans is None:
-            return g_conf, None
-        _, alos, ahis = scans
-        s_conf = np.zeros(len(alos), dtype=bool)
-        if wlo is not None:
-            whi = np.concatenate(slot["whi"])
-            s_conf |= ((alos[:, None] < whi[None, :]) &
-                       (ahis[:, None] > wlo[None, :])).any(axis=1)
-        if wk is not None:
-            s_conf |= ((wk[None, :] >= alos[:, None]) &
-                       (wk[None, :] < ahis[:, None])).any(axis=1)
-        return g_conf, s_conf
+
+# Cells of one reads x writes overlap block: bounds the planner's memory
+# on very large mixed batches.
+_OVERLAP_BLOCK = 1 << 20
+
+
+def _last_overlap(rlo, rhi, rseg, wlo, whi, wseg) -> np.ndarray:
+    """Per read interval [rlo, rhi], the largest ``wseg`` among the write
+    intervals [wlo, whi] overlapping it with ``wseg`` below the read's
+    ``rseg``, or -1 (all bounds inclusive)."""
+    out = np.full(len(rlo), -1, np.int64)
+    if len(wlo) == 0:
+        return out
+    # Writes along the first axis: the max reduces across whole rows.
+    wlo, whi, wseg = wlo[:, None], whi[:, None], wseg[:, None]
+    cols = max(1, _OVERLAP_BLOCK // len(wlo))
+    for a in range(0, len(rlo), cols):
+        b = a + cols
+        hit = ((rlo[a:b] <= whi) & (wlo <= rhi[a:b]) & (wseg < rseg[a:b]))
+        out[a:b] = np.where(hit, wseg, -1).max(axis=0)
+    return out
