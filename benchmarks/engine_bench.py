@@ -76,7 +76,7 @@ def gloran_cfg() -> GloranConfig:
 
 
 def engine_cfg(fused: bool = True, cascade: bool = True) -> EngineConfig:
-    return EngineConfig(cache_blocks=16384, procs=0,
+    return EngineConfig(cache_blocks=16384,
                         use_bloom_kernel=fused, use_interval_kernel=fused,
                         use_cascade_kernel=fused and cascade,
                         kernel_min_batch=128, kernel_min_areas=64,
@@ -180,7 +180,7 @@ def cascade_sweep() -> list[dict]:
         n_rdel = int(PRELOAD * ratio / 4)
         engines = {}
         for name, cascade in (("cascade", True), ("per_level", False)):
-            cfg = EngineConfig(cache_blocks=0, procs=0, use_bloom_kernel=True,
+            cfg = EngineConfig(cache_blocks=0, use_bloom_kernel=True,
                                use_interval_kernel=True,
                                use_cascade_kernel=cascade,
                                kernel_min_batch=128, kernel_min_areas=64,

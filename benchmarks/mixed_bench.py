@@ -88,7 +88,6 @@ ensure_host_devices(4)
 from repro.core import (GloranConfig, LSMDRTreeConfig, RAEConfig, RTree,  # noqa: E402
                         StagingBuffer, disjointize)
 from repro.engine import Engine, EngineConfig, OpBatch  # noqa: E402
-from repro.kernels.dispatch import on_tpu  # noqa: E402
 from repro.lsm import LSMConfig  # noqa: E402
 SCALE = 4 if os.environ.get("REPRO_BENCH_SCALE") == "full" else 1
 OUT = os.environ.get("REPRO_BENCH_OUT", "BENCH_mixed.json")
@@ -144,8 +143,7 @@ def gloran_cfg() -> GloranConfig:
         eve=RAEConfig(capacity=100_000, key_universe=UNIVERSE))
 
 
-def engine_cfg(pipeline: bool, devices: int | None = None,
-               procs: int = 0) -> EngineConfig:
+def engine_cfg(pipeline: bool, devices: int | None = None) -> EngineConfig:
     # Kernel-heavy gating (the TPU-deployment stand-in, as in
     # engine_bench's fused-filter rows): every SSTable filter and
     # DR-tree level probe runs through the Pallas kernels, so the
@@ -159,13 +157,11 @@ def engine_cfg(pipeline: bool, devices: int | None = None,
     # scheduler is pinned off: the legacy sweep rows measure the
     # pipelined-vs-serial architecture and must not drift across the CI
     # REPRO_ENGINE_BG_COMPACT matrix cells; the background scheduler
-    # has its own dedicated section (``bench_bg_scheduler``).  procs is
-    # pinned the same way (default 0, in-process): only
-    # ``bench_proc_parallel`` runs worker processes, explicitly.
+    # has its own dedicated section (``bench_bg_scheduler``).
     return EngineConfig(partition="range", pipeline=pipeline,
                         cache_blocks=0, kernel_min_batch=32,
                         kernel_min_areas=32, kernel_min_filter=512,
-                        devices=devices, scheduler=False, procs=procs)
+                        devices=devices, scheduler=False)
 
 
 def preload_keys() -> np.ndarray:
@@ -174,10 +170,10 @@ def preload_keys() -> np.ndarray:
 
 
 def make_engine(shards: int, pipeline: bool,
-                devices: int | None = None, procs: int = 0) -> Engine:
+                devices: int | None = None) -> Engine:
     eng = Engine(num_shards=shards, strategy="gloran",
                  lsm_config=lsm_cfg(), gloran_config=gloran_cfg(),
-                 config=engine_cfg(pipeline, devices, procs))
+                 config=engine_cfg(pipeline, devices))
     keys = preload_keys()
     for i in range(0, len(keys), 8192):
         kk = keys[i:i + 8192]
@@ -247,8 +243,6 @@ def run_batches(eng: Engine, batches: list[OpBatch]) -> float:
 
 
 def shard_io(eng: Engine) -> list[int]:
-    # Surface accessors, not sh.tree.io: proc shards have no local tree
-    # (the mirrors update on every reply, so this stays cheap).
     return [sh.io_reads + sh.io_writes for sh in eng.shards]
 
 
@@ -468,7 +462,7 @@ def bench_wal_overhead() -> dict:
     def one_pass(wal_dir: str | None) -> tuple[float, dict | None]:
         cfg = EngineConfig(partition="range", pipeline=False, devices=0,
                            wal_dir=wal_dir, fsync="batch",
-                           scheduler=False, procs=0)
+                           scheduler=False)
         eng = Engine(num_shards=2, strategy="gloran",
                      lsm_config=lsm_cfg(), gloran_config=gloran_cfg(),
                      config=cfg)
@@ -623,7 +617,6 @@ def bench_bg_scheduler() -> dict:
 
     def one_side(background: bool) -> dict:
         cfg = EngineConfig(partition="range", pipeline=False, devices=0,
-                           procs=0,
                            kernel_min_batch=32, kernel_min_areas=32,
                            kernel_min_filter=512,
                            scheduler=background, max_frozen=4,
@@ -679,102 +672,6 @@ def bench_bg_scheduler() -> dict:
           f"{bg['upload_bytes'] / 1e6:.1f}MB "
           f"(ratio {out['upload_bytes_ratio']}), "
           f"{out['sched']['proactive_jobs']} proactive jobs", flush=True)
-    return out
-
-
-def bench_proc_parallel() -> dict:
-    """Process-parallel shard execution: MEASURED compute-bound wall.
-
-    The thread pipeline overlaps I/O waits and kernel dispatch but the
-    GIL serializes the simulator's host compute; worker processes are
-    the answer for compute-bound stores.  This section measures exactly
-    that regime: ``io_wait_s = 0`` (no sleeps to overlap — pure host
-    compute), serial in-process single-thread baseline (``procs=0,
-    pipeline=False, devices=0``) vs one worker process per shard
-    (``procs=shards``, shared-memory columnar transport) at the max
-    shard count, identical preloaded stores both sides.
-
-    The measured mix is read-only (gets + scans, no range deletes) so
-    store state is byte-identical across the interleaved serial/proc
-    reps — every rep re-executes the same plans against the same tree.
-    The reported ``proc_wall_speedup`` (median per-rep serial/proc
-    ratio) is the gated figure; it scales with the host's cores, so
-    ``host_cpus`` rides along and scripts/check.sh gates core-aware
-    (>= 1.8x needs >= 4 usable cores; a 1-core box only measures the
-    transport overhead, floor-gated for sanity).
-
-    Per-row transport overhead comes from the engine's ``proc`` ledger:
-    bytes shipped each way over the shared-memory rings and the
-    enqueue->dequeue latency histogram (t_send stamped at token send,
-    compared against monotonic clock at worker receive — comparable
-    across processes, CLOCK_MONOTONIC system-wide).
-    """
-    if on_tpu():
-        why = ("skipped on a TPU backend: the chip belongs to one "
-               "process, so the engine refuses worker processes there")
-        print(f"# proc parallel {why}", flush=True)
-        return {"skipped": why}
-    shards = max(SHARDS)
-    mix = (0.80, 0.20, 0.0)
-    host_cpus = len(os.sched_getaffinity(0))
-    rounds, reps = ROUNDS, max(REPS, 3)
-    batches = mixed_batches(mix, rounds * reps, seed=83)
-    engines = {"serial": make_engine(shards, False, devices=0, procs=0),
-               "proc": make_engine(shards, True, devices=0,
-                                   procs=shards)}
-    for eng in engines.values():  # warm jit (workers compile their own)
-        eng.submit(batches[0]).wait()
-    walls: dict = {"serial": [], "proc": []}
-    for rep in range(reps):
-        rep_batches = batches[1 + rep * rounds:1 + (rep + 1) * rounds]
-        for side in ("serial", "proc"):
-            walls[side].append(run_batches(engines[side], rep_batches))
-    n_ops = rounds * BATCH
-    speedup = round(float(np.median(
-        [s / p for s, p in zip(walls["serial"], walls["proc"])])), 2)
-    st = engines["proc"].stats()
-    t = st["proc"]
-    dq = t["dequeue_latency_us"]
-    rows = []
-    for side in ("serial", "proc"):
-        w = float(np.median(walls[side]))
-        row = {
-            "mode": side,
-            "shards": shards,
-            "workers": shards if side == "proc" else 0,
-            "io_wait_s": 0.0,
-            "wall_seconds": round(sum(walls[side]), 4),
-            "wall_ops_per_sec": round(n_ops / w, 1),
-        }
-        if side == "proc":
-            row["transport"] = {
-                "requests": t["requests"],
-                "bytes_sent": t["bytes_sent"],
-                "bytes_received": t["bytes_received"],
-                "bytes_per_request": round(
-                    (t["bytes_sent"] + t["bytes_received"])
-                    / max(t["requests"], 1), 1),
-                "dequeue_p50_us": dq["p50_us"],
-                "dequeue_p99_us": dq["p99_us"],
-            }
-        rows.append(row)
-    for eng in engines.values():
-        eng.close()
-    out = {
-        "shards": shards,
-        "workers": shards,
-        "host_cpus": host_cpus,
-        "mix": mix,
-        "reps": reps,
-        "ops_per_rep": n_ops,
-        "rows": rows,
-        "proc_wall_speedup": speedup,
-    }
-    print(f"# proc parallel x{shards} workers ({host_cpus} cpus): "
-          f"serial {sum(walls['serial']):.3f}s -> proc "
-          f"{sum(walls['proc']):.3f}s ({speedup}x), "
-          f"{(t['bytes_sent'] + t['bytes_received']) / 1e6:.1f} MB "
-          f"shipped, dequeue p99 {dq['p99_us']:.0f}us", flush=True)
     return out
 
 
@@ -834,7 +731,6 @@ def run() -> dict:
     wal = bench_wal_overhead()
     flm = bench_flush_materialize()
     bg = bench_bg_scheduler()
-    proc = bench_proc_parallel()
     result = {
         "config": {
             "preload_entries": PRELOAD,
@@ -859,7 +755,6 @@ def run() -> dict:
         "wal": wal,
         "flush_materialize": flm,
         "bg_scheduler": bg,
-        "proc_parallel": proc,
         "acceptance": {
             # Background compaction gates (scripts/check.sh): the put
             # p99 under the delete-heavy session-expiry stream must be
@@ -895,16 +790,6 @@ def run() -> dict:
             # serial single-device path, worst mix at >= 2 shards.
             "min_wall_speedup_ge2_shards": min(
                 (r["wall_speedup"] for r in timed_rows), default=None),
-            # Process-parallel gate: measured COMPUTE-BOUND wall
-            # (io_wait_s=0, no sleeps to overlap — the regime threads
-            # can't speed up), one worker process per shard vs serial
-            # in-process.  Core-aware in check.sh: the required ratio
-            # depends on proc_host_cpus.
-            "proc_wall_speedup": proc.get("proc_wall_speedup"),
-            "proc_host_cpus": proc.get("host_cpus"),
-            "proc_transport_dequeue_p99_us":
-                proc["rows"][1]["transport"]["dequeue_p99_us"]
-                if "rows" in proc else None,
             "wall_speedup_max_shards": {
                 r["mix"]: r["wall_speedup"] for r in timed_rows
                 if r["shards"] == max_s},
@@ -918,10 +803,8 @@ def run() -> dict:
           f"speedup = "
           f"{result['acceptance']['geomean_pipeline_speedup_max_shards']}"
           f"x, min timed wall speedup (>=2 shards) = "
-          f"{result['acceptance']['min_wall_speedup_ge2_shards']}x, "
-          f"proc wall speedup = "
-          f"{result['acceptance']['proc_wall_speedup']}x on "
-          f"{result['acceptance']['proc_host_cpus']} cpus", flush=True)
+          f"{result['acceptance']['min_wall_speedup_ge2_shards']}x",
+          flush=True)
     return result
 
 

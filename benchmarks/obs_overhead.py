@@ -54,7 +54,7 @@ def make_engine() -> tuple[Engine, np.ndarray]:
         num_shards=4, strategy="gloran",
         lsm_config=LSMConfig(buffer_capacity=4096, key_size=16,
                              value_size=48, key_universe=UNIVERSE),
-        config=EngineConfig(partition="range", pipeline=True, procs=0,
+        config=EngineConfig(partition="range", pipeline=True,
                             cache_blocks=0, kernel_min_batch=32,
                             kernel_min_areas=32, kernel_min_filter=512))
     keys = np.random.default_rng(5).integers(

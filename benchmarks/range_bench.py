@@ -70,7 +70,7 @@ def engine_cfg() -> EngineConfig:
     # Lower launch gate than engine_bench: EVE's negative probes prune
     # most scan candidates before the index, so the surviving batches
     # are small but still worth one launch per level per scan batch.
-    return EngineConfig(partition="range", cache_blocks=16384, procs=0,
+    return EngineConfig(partition="range", cache_blocks=16384,
                         kernel_min_batch=32, kernel_min_areas=64,
                         kernel_min_filter=4096)
 

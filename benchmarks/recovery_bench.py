@@ -57,7 +57,6 @@ def build_store(wal_dir: str, n_batches: int, *,
                 snapshot_at: int | None = None) -> Engine:
     lsm, glo = cfgs()
     cfg = EngineConfig(partition="range", pipeline=False, devices=0,
-                       procs=0,
                        wal_dir=wal_dir, fsync="rotate")
     eng = Engine(SHARDS, strategy="gloran", lsm_config=lsm,
                  gloran_config=glo, config=cfg)
@@ -98,8 +97,7 @@ def bench_row(n_batches: int, *, with_snapshot: bool) -> dict:
             for root, _, files in os.walk(tmp) for f in files
             if f.endswith(".wal"))
         t0 = time.perf_counter()
-        rec = recover(tmp, config=EngineConfig(procs=0, devices=0,
-                                               pipeline=False))
+        rec = recover(tmp, config=EngineConfig(devices=0, pipeline=False))
         wall = time.perf_counter() - t0
         verify(eng, rec)
         row = {

@@ -154,8 +154,7 @@ def drive(wl: dict, devices: int) -> dict:
     lsm, gloran = store_configs()
     eng = Engine(num_shards=SHARDS, strategy="gloran", lsm_config=lsm,
                  gloran_config=gloran,
-                 config=EngineConfig(partition="hash", devices=devices,
-                                     procs=0))
+                 config=EngineConfig(partition="hash", devices=devices))
     walls = {}
     model: dict = {}
     t0 = time.perf_counter()

@@ -57,7 +57,6 @@ def make_batches():
 def engine_config(wal_dir):
     from repro.engine import EngineConfig
     return EngineConfig(partition="hash", pipeline=False, devices=0,
-                        procs=0,
                         wal_dir=wal_dir, fsync="batch")
 
 
@@ -156,8 +155,7 @@ def parent_main() -> int:
     # now, with the child dead: a TPU belongs to one process at a time.
     from repro.durable import recover
     from repro.engine import EngineConfig
-    rec = recover(wal_dir, config=EngineConfig(procs=0, devices=0,
-                                               pipeline=False))
+    rec = recover(wal_dir, config=EngineConfig(devices=0, pipeline=False))
     print(f"recovered: {rec.recovery}")
 
     # Acked-prefix state must be FULLY present.  The batch after the

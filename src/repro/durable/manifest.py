@@ -122,14 +122,7 @@ class LevelManifest:
     def record_structure(self, shard: int, tree, *, reason: str) -> int:
         """One structural edit (flush / compaction / GC / recover):
         replace the shard's level record and commit a new version."""
-        return self.record_structure_desc(shard, describe_tree(tree),
-                                          reason=reason)
-
-    def record_structure_desc(self, shard: int, desc: dict, *,
-                              reason: str) -> int:
-        """Commit a pre-described level record — how structure edits
-        from shard worker processes (which describe their own trees and
-        ship the document home) land in the parent's manifest."""
+        desc = describe_tree(tree)
         with self._lock:
             self.doc["shards"][str(shard)] = desc
             self.doc["edits"].append({

@@ -93,7 +93,7 @@ def recover(wal_dir: str, *, config=None, use_snapshot: bool = True):
     """
     from dataclasses import replace
 
-    from ..engine.engine import Engine, _resolve_procs
+    from ..engine.engine import Engine
     from ..engine.executor import EngineConfig
 
     t0 = time.perf_counter()
@@ -108,18 +108,6 @@ def recover(wal_dir: str, *, config=None, use_snapshot: bool = True):
     # the non-empty directory; writers re-attach after replay.
     cfg = replace(config or EngineConfig(), partition=partition,
                   wal_dir=None)
-
-    # Procs mode: each worker replays its own shard streams during
-    # startup (WAL ownership lives with the worker), the parent loads
-    # the manifest and records the shipped-back "recover" level
-    # records.  No snapshot fast path — worker trees rebuild from the
-    # full log (take_snapshot is refused on procs engines anyway).
-    if _resolve_procs(cfg, num_shards):
-        engine = Engine(num_shards, strategy=strategy, lsm_config=lsm,
-                        gloran_config=gloran, config=cfg,
-                        _recover_from=wal_dir)
-        engine.recovery["wall_s"] = time.perf_counter() - t0
-        return engine
 
     def fresh() -> "Engine":
         return Engine(num_shards, strategy=strategy, lsm_config=lsm,

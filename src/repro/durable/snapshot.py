@@ -161,12 +161,6 @@ def take_snapshot(engine, directory: str | None = None, *,
     """Drain, publish a snapshot, and point the manifest at it (with the
     per-shard WAL positions it covers) so the next restart replays only
     the tail.  ``directory`` defaults to ``<wal_dir>/snapshots``."""
-    if getattr(engine, "procs", 0):
-        raise RuntimeError(
-            "take_snapshot needs direct tree access, but this engine's "
-            "shards live in worker processes (EngineConfig.procs / "
-            "REPRO_ENGINE_PROCS); procs-mode stores recover by full WAL "
-            "replay — snapshot from an in-process (procs=0) engine")
     engine.drain()
     if directory is None:
         if not engine.wal_dir:

@@ -23,7 +23,6 @@ from .cache import BlockCache
 from .engine import Engine
 from .executor import EngineConfig, ShardExecutor
 from .pending import PendingBatch
-from .procpool import ProcPool, ProcShard, WorkerSpec
 from .plan import (KIND_CODES, KIND_NAMES, OP_DELETE, OP_GET, OP_PUT,
                    OP_RANGE_DELETE, OP_RANGE_SCAN, OpBatch, Plan, Planner,
                    PlanStep, ShardPlan)
@@ -34,8 +33,7 @@ from .stats import EngineStats, KernelCounters, merge_io_snapshots
 __all__ = ["BlockCache", "Engine", "EngineConfig", "ShardExecutor",
            "ShardRouter", "EngineStats", "KernelCounters",
            "merge_io_snapshots", "OpBatch", "Plan", "Planner", "PlanStep",
-           "ShardPlan", "PendingBatch", "ProcPool", "ProcShard",
-           "WorkerSpec", "CascadeView",
+           "ShardPlan", "PendingBatch", "CascadeView",
            "DeviceFilterRegistry", "KIND_CODES", "KIND_NAMES",
            "OP_PUT", "OP_DELETE", "OP_GET", "OP_RANGE_DELETE",
            "OP_RANGE_SCAN"]

@@ -22,7 +22,6 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 
 from ..core.gloran import GloranConfig
-from ..kernels.dispatch import on_tpu
 from ..launch.mesh import shard_devices
 from ..lsm import LSMConfig, LSMTree
 from ..lsm.merge import merge_runs
@@ -62,28 +61,6 @@ def _resolve_devices(config: EngineConfig, num_shards: int) -> list | None:
             return None
         want = min(num_shards, avail)
     return shard_devices(num_shards, limit=want)
-
-
-def _resolve_procs(config: EngineConfig, num_shards: int) -> int:
-    """Worker-process count, or 0 for the in-process path.
-
-    ``EngineConfig.procs`` wins; None defers to ``REPRO_ENGINE_PROCS``;
-    unset/0 = off (byte-identical in-process execution).  N spawns
-    min(N, num_shards) workers, shards assigned round-robin; refused on
-    a TPU backend, before any worker is spawned.
-    """
-    want = config.procs
-    if want is None:
-        env = os.environ.get("REPRO_ENGINE_PROCS", "").strip()
-        want = int(env) if env else 0
-    want = int(want or 0)
-    if want > 0 and on_tpu():
-        raise RuntimeError(
-            f"procs={want}: shard worker processes each dispatch JAX "
-            "kernels, and a TPU belongs to one process, which this "
-            "engine's process already holds — run procs=0 on a TPU "
-            "backend (unset EngineConfig.procs / REPRO_ENGINE_PROCS)")
-    return min(want, num_shards) if want > 0 else 0
 
 
 def _merge_cache_snaps(snaps: list) -> dict:
@@ -137,8 +114,7 @@ class Engine:
     def __init__(self, num_shards: int = 1, strategy: str = "gloran",
                  lsm_config: LSMConfig | None = None,
                  gloran_config: GloranConfig | None = None,
-                 config: EngineConfig | None = None,
-                 _recover_from: str | None = None):
+                 config: EngineConfig | None = None):
         self.config = config or EngineConfig()
         self.num_shards = int(num_shards)
         self.strategy = strategy
@@ -170,55 +146,25 @@ class Engine:
         self.background = bool(sched)
         # A directory that already holds acknowledged frames is refused
         # — recovery must fold them in first, or acked writes would be
-        # silently orphaned.  (``_recover_from`` is that fold-in:
-        # ``repro.durable.recover`` passes it in procs mode so each
-        # worker replays its own stream before serving.)
-        if self.config.wal_dir and not _recover_from:
+        # silently orphaned.
+        if self.config.wal_dir:
             from ..durable.wal import wal_has_frames
             if wal_has_frames(self.config.wal_dir):
                 raise RuntimeError(
                     f"WAL at {self.config.wal_dir} holds acknowledged "
                     "frames; open it with repro.durable.recover() "
                     "instead of a fresh Engine")
-        # Process-parallel shard execution (engine/procpool.py):
-        # ``EngineConfig.procs`` / REPRO_ENGINE_PROCS; 0 = in-process.
-        self.procs = _resolve_procs(self.config, self.num_shards)
-        self._proc_pool = None
-        if _recover_from and not self.procs:
-            raise RuntimeError("_recover_from is the procs-mode "
-                               "recovery path; use durable.recover()")
-        if self.procs:
-            from .procpool import ProcPool
-            if self.devices is not None:
-                import jax
-                device_ids = [d.id for d in self.devices]
-                host_devices = len(jax.devices())
-            else:
-                device_ids = [None] * self.num_shards
-                host_devices = 1
-            self._proc_pool = ProcPool(
-                num_shards=self.num_shards, procs=self.procs,
-                strategy=strategy, lsm_config=base,
-                gloran_config=gloran_config, config=self.config,
-                background=self.background, device_ids=device_ids,
-                host_devices=host_devices,
-                wal_dir=self.config.wal_dir or _recover_from,
-                replay=bool(_recover_from))
-            self.shards = self._proc_pool.shards
-        else:
-            self.shards = []
-            for s in range(self.num_shards):
-                tree = LSMTree(base, strategy=strategy,
-                               gloran_config=gloran_config)
-                dev = (self.devices[s] if self.devices is not None
-                       else None)
-                self.shards.append(ShardExecutor(tree, self.config,
-                                                 device=dev))
-            if self.background:
-                for sh in self.shards:
-                    sh.attach_scheduler(CompactionScheduler(
-                        sh.tree, max_frozen=self.config.max_frozen,
-                        tombstone_trigger=self.config.tombstone_trigger))
+        self.shards = []
+        for s in range(self.num_shards):
+            tree = LSMTree(base, strategy=strategy,
+                           gloran_config=gloran_config)
+            dev = self.devices[s] if self.devices is not None else None
+            self.shards.append(ShardExecutor(tree, self.config, device=dev))
+        if self.background:
+            for sh in self.shards:
+                sh.attach_scheduler(CompactionScheduler(
+                    sh.tree, max_frozen=self.config.max_frozen,
+                    tombstone_trigger=self.config.tombstone_trigger))
         self.stats_ = EngineStats()
         self.metrics = MetricsRegistry()
         pl = self.config.pipeline
@@ -229,45 +175,13 @@ class Engine:
         self._inflight: list[PendingBatch] = []
         self._inflight_lock = threading.Lock()
         # Durability (repro.durable): a configured wal_dir attaches a
-        # per-shard WAL stream + the level manifest.  In procs mode the
-        # WAL writers live INSIDE the workers (append-before-ack holds
-        # within each worker's run_plan); the parent owns the manifest,
-        # applying structure edits shipped back with each reply.
+        # per-shard WAL stream + the level manifest.
         self.wal_dir: str | None = None
         self.manifest = None
         self.recovery = {"wall_s": 0.0, "frames_replayed": 0,
                          "snapshot_loaded": 0}
-        if self.procs:
-            d = self.config.wal_dir or _recover_from
-            if d:
-                self._attach_proc_durability(
-                    d, recovered=bool(_recover_from))
-        elif self.config.wal_dir:
+        if self.config.wal_dir:
             self._attach_durability(self.config.wal_dir)
-
-    def _attach_proc_durability(self, wal_dir: str, *,
-                                recovered: bool) -> None:
-        """Procs-mode durability wiring: manifest in the parent, WAL
-        writers in the workers (already attached by ProcPool)."""
-        from ..durable.manifest import LevelManifest, engine_config_doc
-        self.wal_dir = wal_dir
-        if recovered:
-            manifest = LevelManifest.load(os.path.join(wal_dir,
-                                                       "manifest"))
-        else:
-            manifest = LevelManifest(
-                os.path.join(wal_dir, "manifest"),
-                config=engine_config_doc(self), fsync=False)
-            manifest.commit(fsync=self.config.fsync != "never")
-        self.manifest = manifest
-        for sh in self.shards:
-            sh.manifest = manifest
-        if recovered:
-            for s, desc in sorted(
-                    self._proc_pool.recovered_descs.items()):
-                manifest.record_structure_desc(s, desc, reason="recover")
-            self.recovery["frames_replayed"] = \
-                self._proc_pool.frames_replayed
 
     def _attach_durability(self, wal_dir: str, *, manifest=None,
                            writers: list | None = None) -> None:
@@ -428,11 +342,8 @@ class Engine:
             for p in self._pools:
                 p.shutdown(wait=True)
             self._pools = None
-        if self._proc_pool is not None:
-            self._proc_pool.close()
-        else:
-            for sh in self.shards:
-                sh.close()
+        for sh in self.shards:
+            sh.close()
 
     def __enter__(self) -> "Engine":
         return self
@@ -548,12 +459,10 @@ class Engine:
 
     def stats(self) -> dict:
         self.drain()
-        # ONE per-shard ledger document each — in-process executors
-        # read their tree directly, proc shards round-trip a STATS
-        # message to their worker.  Everything below aggregates these
-        # documents only, so both modes share one code path, and the
-        # values are cumulative snapshots: calling stats() twice
-        # without intervening work returns identical numbers.
+        # ONE per-shard ledger document each; everything below
+        # aggregates these documents only, and the values are cumulative
+        # snapshots: calling stats() twice without intervening work
+        # returns identical numbers.
         fulls = [sh.stats_full() for sh in self.shards]
         staging = [{"shard": s, **f["staging"]}
                    for s, f in enumerate(fulls)
@@ -567,7 +476,6 @@ class Engine:
             "num_shards": self.num_shards,
             "partition": self.router.partition,
             "pipeline": self.pipeline_default,
-            "procs": self.procs,
             "devices": {
                 "enabled": self.devices is not None,
                 "distinct": len(set(self.device_map().values())),
@@ -647,15 +555,6 @@ class Engine:
                     agg[k] = agg.get(k, 0) + v
             out["wal"] = agg
             m.absorb("wal", agg)
-        # Shared-memory transport ledger (procs mode): bytes shipped
-        # each way + the enqueue->dequeue latency histogram.
-        if self._proc_pool is not None:
-            t = self._proc_pool.transport_snapshot()
-            out["proc"] = t
-            m.absorb("proc", {k: v for k, v in t.items()
-                              if k != "dequeue_latency_us"})
-            m.absorb("proc.dequeue_latency_us",
-                     t["dequeue_latency_us"])
         m.absorb("recovery", self.recovery)
         out["metrics"] = m.snapshot()
         return out

@@ -130,15 +130,16 @@ class EngineConfig:
     # default — proactive compaction intentionally diverges from the
     # inline level shapes to reclaim GLORAN garbage early).
     tombstone_trigger: float | None = None
-    # Process-parallel shard execution (engine/procpool.py): None = env
-    # (REPRO_ENGINE_PROCS; unset/0 = off — the in-process path,
-    # byte-identical).  N spawns min(N, num_shards) worker processes,
-    # shards assigned round-robin, ShardPlans shipped as shared-memory
-    # columnar frames — real multi-core wall speedup on compute-bound
-    # work the GIL otherwise serializes.
-    procs: int | None = None
-    # Capacity of each per-direction shared-memory transport ring.
-    proc_ring_bytes: int = 32 << 20
+    # Shards always execute in this process: a TPU belongs to one
+    # process.  The field remains only for callers that still pass
+    # ``procs=0``; any other value is refused.
+    procs: int = 0
+
+    def __post_init__(self):
+        if self.procs != 0:
+            raise ValueError(
+                f"procs={self.procs}: worker-process shard execution was "
+                "removed; shards run in the engine's process (procs=0)")
 
 
 class ShardExecutor:
@@ -284,9 +285,7 @@ class ShardExecutor:
         self._maybe_record_structure(fp0, "flush")
 
     # ------------------------------------------------ uniform surface
-    # The engine aggregates shards through these accessors ONLY, so an
-    # in-process executor and a ``procpool.ProcShard`` proxy (whose tree
-    # lives in a worker process) are interchangeable.
+    # The engine aggregates shards through these accessors only.
     @property
     def io_reads(self) -> int:
         return self.tree.io.reads
@@ -304,7 +303,7 @@ class ShardExecutor:
 
     def stats_full(self) -> dict:
         """Every per-shard ledger ``engine.stats()`` rolls up, in one
-        JSON-able document (the procpool STATS reply body)."""
+        JSON-able document."""
         from ..lsm.scheduler import level_rt_density
         tree = self.tree
         return {

@@ -66,8 +66,8 @@ class KernelCounters:
 
     @classmethod
     def from_snapshot(cls, snap: dict) -> "KernelCounters":
-        """Inverse of ``snapshot()`` — how a shard-worker reply's
-        cumulative kernel ledger rehydrates on the parent side."""
+        """Inverse of ``snapshot()`` — how a shard's ``stats_full``
+        kernel ledger rehydrates for the engine's rollup."""
         out = cls()
         for k, v in (snap or {}).items():
             if k in ("upload_bytes_by_device", "pack_bytes_by_device"):

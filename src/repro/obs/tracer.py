@@ -153,11 +153,6 @@ class Tracer:
         self.max_events = int(max_events)
         self.dropped = 0
         self._events: list[tuple] = []
-        # Spans absorbed from other processes (shard workers): same
-        # tuple shape prefixed with (pid, process name).  perf_counter
-        # is CLOCK_MONOTONIC system-wide on Linux, so foreign
-        # timestamps land on this tracer's clock directly.
-        self._foreign: list[tuple] = []
         self._lock = threading.Lock()
         self._base = time.perf_counter()
         # Imported here, not at module import: the NullTracer path never
@@ -172,39 +167,8 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
-            self._foreign.clear()
             self.dropped = 0
             self._base = time.perf_counter()
-
-    # --------------------------------------------- cross-process merge
-    def drain(self) -> list[list]:
-        """Return + clear the recorded spans as JSON-able rows — the
-        shipping format a shard worker sends home with each reply.  The
-        epoch is kept, so successive drains stay on one timeline."""
-        with self._lock:
-            # Copy, then cut what was copied: a span recorded meanwhile
-            # stays for the next drain.
-            snap = self._events[:]
-            del self._events[:len(snap)]
-        return [[n, t0, t1, cpu, tid, tname, attrs]
-                for n, t0, t1, cpu, tid, tname, attrs in snap]
-
-    def absorb(self, rows: list, *, pid: int,
-               process_name: str | None = None) -> None:
-        """Merge spans drained in another process into this trace,
-        keyed under that process's pid so the Chrome export renders one
-        named track group per worker."""
-        with self._lock:
-            for r in rows:
-                if (len(self._events) + len(self._foreign)
-                        >= self.max_events):
-                    self.dropped += 1
-                    continue
-                self._foreign.append((int(pid), process_name, r[0],
-                                      float(r[1]), float(r[2]),
-                                      None if r[3] is None
-                                      else float(r[3]),
-                                      int(r[4]), r[5], r[6] or {}))
 
     # ------------------------------------------------------------ views
     def events(self) -> list[dict]:
@@ -219,24 +183,22 @@ class Tracer:
     def chrome_events(self) -> list[dict]:
         """Chrome trace-event list: complete ('X') events in microseconds
         relative to the tracer epoch, plus thread/process name metadata
-        so Perfetto labels each shard worker's track.  Each event's
+        so Perfetto labels each shard thread's track.  Each event's
         ``args`` holds the span's attrs, and ``cpu_ms`` where the span
         took its thread's CPU time."""
         with self._lock:
             snap = list(self._events)
-            foreign = list(self._foreign)
             base = self._base
         out = [{"name": "process_name", "ph": "M", "pid": 1, "tid": 0,
                 "args": {"name": "repro-engine"}}]
-        seen: dict[tuple, str] = {}
-
-        def emit(pid, name, t0, t1, cpu, tid, tname, attrs):
-            if (pid, tid) not in seen:
-                seen[(pid, tid)] = tname
-                out.append({"name": "thread_name", "ph": "M", "pid": pid,
+        seen: set[int] = set()
+        for name, t0, t1, cpu, tid, tname, attrs in snap:
+            if tid not in seen:
+                seen.add(tid)
+                out.append({"name": "thread_name", "ph": "M", "pid": 1,
                             "tid": tid, "args": {"name": tname}})
             ev = {"name": name, "cat": name.split(".", 1)[0], "ph": "X",
-                  "pid": pid, "tid": tid,
+                  "pid": 1, "tid": tid,
                   "ts": round((t0 - base) * 1e6, 3),
                   "dur": round((t1 - t0) * 1e6, 3)}
             if cpu is not None:
@@ -244,17 +206,6 @@ class Tracer:
             if attrs:
                 ev["args"] = attrs
             out.append(ev)
-
-        for name, t0, t1, cpu, tid, tname, attrs in snap:
-            emit(1, name, t0, t1, cpu, tid, tname, attrs)
-        pids_named: set[int] = set()
-        for pid, pname, name, t0, t1, cpu, tid, tname, attrs in foreign:
-            if pid not in pids_named:
-                pids_named.add(pid)
-                out.append({"name": "process_name", "ph": "M",
-                            "pid": pid, "tid": 0,
-                            "args": {"name": pname or f"pid {pid}"}})
-            emit(pid, name, t0, t1, cpu, tid, tname, attrs)
         return out
 
     def export_chrome(self, path: str) -> dict:

@@ -58,17 +58,12 @@ class SessionRegistry:
                  gloran_config: GloranConfig | None = None,
                  num_shards: int = 1,
                  engine_config: EngineConfig | None = None):
-        # The registry's ``tree`` property (and the strategy-comparison
-        # harnesses built on it) introspect the backing LSMTree
-        # directly, so the default engine stays in-process even under a
-        # REPRO_ENGINE_PROCS environment; pass an explicit
-        # ``engine_config`` to serve from worker processes.
         self.engine = Engine(
             num_shards=num_shards, strategy=strategy,
             lsm_config=lsm_config or LSMConfig(buffer_capacity=4096,
                                                key_size=16, value_size=48),
             gloran_config=gloran_config,
-            config=engine_config or EngineConfig(procs=0))
+            config=engine_config)
 
     @property
     def tree(self):

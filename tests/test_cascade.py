@@ -57,11 +57,9 @@ def small_gloran(index_buffer=16):
 
 
 def engine_cfg(*, cascade: bool, mode: str = "compiled", **kw):
-    # procs pinned off: this suite reaches into eng.shards[s].tree for
-    # registry/epoch assertions, which needs in-process shards.
     d = dict(cache_blocks=512, kernel_min_batch=1, kernel_min_areas=1,
              kernel_min_filter=1, use_cascade_kernel=cascade,
-             cascade_compiled=(mode == "compiled"), procs=0)
+             cascade_compiled=(mode == "compiled"))
     d.update(kw)
     return EngineConfig(**d)
 

@@ -61,11 +61,9 @@ def small_gloran():
 
 
 def engine_cfg(*, devices, pipeline=None, **kw):
-    # procs pinned off: the device-matrix assertions read per-shard
-    # trees/registries in-process (cross-process parity: test_procs.py).
     d = dict(cache_blocks=512, kernel_min_batch=1, kernel_min_areas=1,
              kernel_min_filter=1, cascade_compiled=True, devices=devices,
-             pipeline=pipeline, procs=0)
+             pipeline=pipeline)
     d.update(kw)
     return EngineConfig(**d)
 

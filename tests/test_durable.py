@@ -52,11 +52,9 @@ def small_gloran():
 
 def make_engine(tmp, *, shards=2, strategy="gloran", fsync="batch",
                 wal=True, segment_bytes=4 << 20):
-    # procs pinned off: replay/snapshot assertions need direct tree
-    # access; procs-mode durability has its own suite in test_procs.py.
     cfg = EngineConfig(wal_dir=str(tmp) if wal else None, fsync=fsync,
                        wal_segment_bytes=segment_bytes, devices=0,
-                       pipeline=False, procs=0)
+                       pipeline=False)
     return Engine(shards, strategy=strategy, lsm_config=small_lsm(),
                   gloran_config=small_gloran(), config=cfg)
 
@@ -255,7 +253,7 @@ def test_recover_full_log_matches_original(tmp_path, strategy):
     eng = make_engine(wdir, shards=2, strategy=strategy)
     apply_workload(eng, mixed_ops(seed=7))
     eng.close()
-    rec = recover(str(wdir), config=EngineConfig(procs=0, devices=0,
+    rec = recover(str(wdir), config=EngineConfig(devices=0,
                                                  pipeline=False))
     assert_same_store(eng, rec)
     rec.close()
@@ -289,7 +287,7 @@ def test_recover_over_fused_write_runs(tmp_path, shards):
     live = [sh.tree.io.snapshot()["by_tag"] for sh in eng.shards]
     assert all(t.get("index_flush") and t.get("compaction") for t in live)
     eng.close()
-    rec = recover(str(wdir), config=EngineConfig(procs=0, devices=0,
+    rec = recover(str(wdir), config=EngineConfig(devices=0,
                                                  pipeline=False))
     assert rec.stats()["executor"]["write_runs_fused"] == 0
     assert_same_store(eng, rec)
@@ -331,7 +329,7 @@ def test_wal_metrics_exposed(tmp_path):
     assert m["wal.frames"] >= 1
     assert m["recovery.wall_s"] == 0.0
     eng.close()
-    rec = recover(str(tmp_path), config=EngineConfig(procs=0, devices=0,
+    rec = recover(str(tmp_path), config=EngineConfig(devices=0,
                                                      pipeline=False))
     m2 = rec.stats()["metrics"]
     assert m2["recovery.wall_s"] > 0.0
@@ -346,7 +344,7 @@ def test_replay_after_explicit_flush_keeps_level_shapes(tmp_path):
     eng.flush()  # structure change outside any plan
     eng.put_batch(keys[20:], keys[20:])
     eng.close()
-    rec = recover(str(tmp_path), config=EngineConfig(procs=0, devices=0,
+    rec = recover(str(tmp_path), config=EngineConfig(devices=0,
                                                      pipeline=False))
     assert_same_store(eng, rec)
     rec.close()
@@ -361,7 +359,7 @@ def test_snapshot_tail_restart(tmp_path, strategy):
     tail_keys = np.arange(30000, 30020, dtype=np.uint64)
     eng.put_batch(tail_keys, tail_keys * 5)
     eng.close()
-    rec = recover(str(tmp_path), config=EngineConfig(procs=0, devices=0,
+    rec = recover(str(tmp_path), config=EngineConfig(devices=0,
                                                      pipeline=False))
     assert rec.recovery["snapshot_loaded"] == 1
     # Only the two post-snapshot frames replayed (WAL-tail restart).
@@ -369,7 +367,7 @@ def test_snapshot_tail_restart(tmp_path, strategy):
     assert_same_store(eng, rec)
     rec.close()
     # A second recovery ignores nothing new and still matches.
-    rec2 = recover(str(tmp_path), config=EngineConfig(procs=0, devices=0,
+    rec2 = recover(str(tmp_path), config=EngineConfig(devices=0,
                                                       pipeline=False))
     assert_same_store(eng, rec2)
     rec2.close()
@@ -386,7 +384,7 @@ def test_snapshot_ignored_when_ahead_of_wal(tmp_path):
     # Simulate the snapshot's WAL foundation vanishing.
     for seg in glob.glob(str(tmp_path / "shard-000" / "*.wal")):
         os.remove(seg)
-    rec = recover(str(tmp_path), config=EngineConfig(procs=0, devices=0,
+    rec = recover(str(tmp_path), config=EngineConfig(devices=0,
                                                      pipeline=False))
     assert rec.recovery["snapshot_loaded"] == 0
     found, _ = rec.get_batch(keys)
@@ -458,7 +456,7 @@ def run_crash_case(tmp, strategy, shards, seed, cut_frac):
     surviving = {s: WalReader(str(wdir), s).read_frames()
                  for s in range(shards)}
 
-    rec = recover(str(wdir), config=EngineConfig(procs=0, devices=0,
+    rec = recover(str(wdir), config=EngineConfig(devices=0,
                                                  pipeline=False))
 
     # Reference: a never-crashed store fed exactly the surviving frames.

@@ -312,7 +312,7 @@ def _fusion_engine(strategy, pipeline=False):
                   lsm_config=small_cfg(buffer_capacity=4096),
                   gloran_config=small_gloran(index_buffer=4096)
                   if strategy == "gloran" else None,
-                  config=EngineConfig(pipeline=pipeline, procs=0,
+                  config=EngineConfig(pipeline=pipeline,
                                       devices=0))
 
 
@@ -400,7 +400,7 @@ class TestWriteRunFusion:
             eng = Engine(num_shards=2, strategy="gloran",
                          lsm_config=small_cfg(buffer_capacity=24),
                          gloran_config=small_gloran(index_buffer=8),
-                         config=EngineConfig(pipeline=False, procs=0,
+                         config=EngineConfig(pipeline=False,
                                              devices=0, io_wait_s=1e-6))
             if not fused:
                 for sh in eng.shards:

@@ -224,7 +224,7 @@ def _gloran_engine(**cfg):
     """2 pipelined GLORAN shards with every kernel gate at its floor and
     a small index buffer, so lookups take the cascade with a GLORAN
     level and compactions take the merge kernel."""
-    d = dict(pipeline=True, procs=0, kernel_min_batch=1,
+    d = dict(pipeline=True, kernel_min_batch=1,
              kernel_min_areas=1, kernel_min_filter=1, kernel_min_merge=16)
     d.update(cfg)
     return Engine(num_shards=2, strategy="gloran",
@@ -434,11 +434,6 @@ def test_metrics_registry_namespacing_and_schema():
 
 # --------------------------------------------------- engine integration
 def _engine(**cfg):
-    # procs pinned to 0: these tests introspect the parent tracer's own
-    # span records (tr.events()) — in procs mode the shard spans are
-    # foreign rows absorbed from the workers and only surface through
-    # chrome_events(); tests/test_procs.py covers that path.
-    cfg.setdefault("procs", 0)
     eng = Engine(num_shards=2, strategy="gloran",
                  lsm_config=LSMConfig(buffer_capacity=64, size_ratio=3,
                                       key_size=16, value_size=48,
@@ -544,7 +539,7 @@ def _engine4():
                                       key_size=16, value_size=48,
                                       block_size=512,
                                       key_universe=UNIVERSE),
-                 config=EngineConfig(pipeline=True, devices=4, procs=0))
+                 config=EngineConfig(pipeline=True, devices=4))
     keys = np.arange(0, 8000, 2, dtype=np.uint64)
     eng.put_batch(keys, keys + np.uint64(1))
     eng.flush()

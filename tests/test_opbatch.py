@@ -409,7 +409,7 @@ def fusion_engine(strategy, shards, scheduler):
     return Engine(num_shards=shards, strategy=strategy,
                   lsm_config=small_cfg(buffer_capacity=24),
                   gloran_config=g if strategy == "gloran" else None,
-                  config=EngineConfig(pipeline=False, procs=0, devices=0,
+                  config=EngineConfig(pipeline=False, devices=0,
                                       scheduler=scheduler))
 
 

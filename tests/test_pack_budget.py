@@ -86,7 +86,7 @@ def _four_device_engine(buffer_capacity):
                                             block_size=512),
                       eve=RAEConfig(capacity=4096, key_universe=UNIVERSE)),
                   config=EngineConfig(partition="range", devices=4,
-                                      procs=0, kernel_min_batch=1))
+                                      kernel_min_batch=1))
 
 
 def _rdels(rng, n, lo, width, span=128):
@@ -190,7 +190,7 @@ def test_pack_and_cascade_spans_name_their_device():
     one = Engine(num_shards=2, strategy="gloran",
                  lsm_config=LSMConfig(buffer_capacity=256,
                                       key_universe=UNIVERSE),
-                 config=EngineConfig(partition="range", devices=0, procs=0,
+                 config=EngineConfig(partition="range", devices=0,
                                      kernel_min_batch=1))
     k2 = np.concatenate([rng.choice(1 << 16, size=2000, replace=False),
                          (UNIVERSE // 2) + rng.choice(1 << 16, size=2000,

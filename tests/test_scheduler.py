@@ -55,7 +55,6 @@ def small_gloran():
 def make_engine(*, strategy="gloran", shards=2, scheduler=False,
                 **cfg_kw):
     cfg_kw.setdefault("pipeline", False)
-    cfg_kw.setdefault("procs", 0)  # suite reads shards[s].scheduler
     cfg = EngineConfig(devices=0, scheduler=scheduler, **cfg_kw)
     return Engine(shards, strategy=strategy, lsm_config=small_lsm(),
                   gloran_config=small_gloran(), config=cfg)

@@ -205,7 +205,7 @@ def _store(offset: int, **kernels) -> Engine:
                   config=EngineConfig(cache_blocks=512, kernel_min_batch=1,
                                       kernel_min_areas=1,
                                       kernel_min_filter=1,
-                                      kernel_min_merge=1, procs=0,
+                                      kernel_min_merge=1,
                                       **kernels))
 
 
