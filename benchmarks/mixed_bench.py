@@ -512,14 +512,14 @@ def bench_wal_overhead() -> dict:
 
 
 def bench_flush_materialize() -> dict:
-    """Memtable->run materialization: row-tuple loop vs columnar sort.
+    """Memtable->run materialization: dict row-tuple loop vs the run.
 
-    The flush path used to materialize the memtable with a Python
-    row-tuple comprehension (``np.array([(k, s, t, v) ...])``); it now
-    reuses the read path's cached columnar snapshot
-    (``LSMTree._mem_sorted`` -> ``build_sstable(presorted=True)``).
-    This micro-bench times both materializations over the same
-    10^5-entry memtable dict and checks they produce identical runs.
+    The memtable used to be a ``key -> (seq, type, val)`` dict that a
+    flush materialized with a Python row-tuple comprehension
+    (``np.array([(k, s, t, v) ...])``); it is now a key-sorted columnar
+    run that the flush hands to ``build_sstable(presorted=True)`` as it
+    stands.  This micro-bench times both over the same 10^5 puts and
+    checks they produce identical runs.
     """
     from repro.lsm.sstable import build_sstable
     from repro.lsm.tree import LSMTree
@@ -533,19 +533,19 @@ def bench_flush_materialize() -> dict:
                    strategy="decomp")
     tree.put_batch(keys, keys + np.uint64(1))
     cfg = tree.config
+    mem = {k: (s, 0, k + 1)
+           for s, k in enumerate(keys.tolist(), start=1)}
 
     def legacy():
         items = np.array([(k, s, t, v)
-                          for k, (s, t, v) in tree.mem.items()],
+                          for k, (s, t, v) in mem.items()],
                          dtype=np.uint64)
         return build_sstable(items[:, 0], items[:, 1],
                              items[:, 2].astype(np.uint8), items[:, 3],
                              cfg)
 
     def columnar():
-        tree._mem_snap = None  # charge the sort to this path
-        mk, ms, mt, mv = tree._mem_sorted()
-        return build_sstable(mk, ms, mt, mv, cfg, presorted=True)
+        return build_sstable(*tree.mem, cfg, presorted=True)
 
     reps = max(REPS, 3)
     walls = {"legacy": [], "columnar": []}

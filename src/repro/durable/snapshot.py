@@ -53,13 +53,9 @@ def _shard_arrays(tree) -> tuple[dict, dict]:
         "levels": [],
         "level_rts": len(tree.level_rts),
     }
-    if tree.mem:
-        rows = np.array([(k, s, t, v)
-                         for k, (s, t, v) in tree.mem.items()],
-                        dtype=np.uint64)
-    else:
-        rows = np.zeros((0, 4), dtype=np.uint64)
-    arrays["mem"] = rows
+    # (k, s, t, v) rows of the memtable run, key-sorted.
+    arrays["mem"] = np.stack([x.astype(np.uint64) for x in tree.mem],
+                             axis=1)
     arrays["mem_rts"] = (np.array(tree.mem_rts, dtype=np.uint64)
                          if tree.mem_rts
                          else np.zeros((0, 3), dtype=np.uint64))
@@ -179,10 +175,12 @@ def take_snapshot(engine, directory: str | None = None, *,
 def _restore_tree(tree, arrays, meta: dict) -> None:
     """Load one shard's saved state into a freshly constructed tree."""
     cfg = tree.config
+    # Sorted on load: older snapshots hold the rows in the memtable's
+    # insertion order (unique keys either way).
     mem = arrays["mem"]
-    tree.mem = {int(k): (int(s), int(t), int(v))
-                for k, s, t, v in mem.tolist()}
-    tree._mem_snap = None
+    mem = mem[np.argsort(mem[:, 0], kind="stable")]
+    tree.mem = (mem[:, 0].copy(), mem[:, 1].copy(),
+                mem[:, 2].astype(np.uint8), mem[:, 3].copy())
     tree.mem_rts = [tuple(int(x) for x in row)
                     for row in arrays["mem_rts"].tolist()]
     tree.seq = int(meta["seq"])

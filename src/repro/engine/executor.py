@@ -443,7 +443,7 @@ class ShardExecutor:
                                     if s.kind == OP_RANGE_DELETE)):
             at = 0
             while at < len(run):
-                room = tree.config.buffer_capacity - len(tree.mem)
+                room = tree.config.buffer_capacity - len(tree.mem[0])
                 end, w = at, 0
                 while end < len(run):
                     step = run[end]

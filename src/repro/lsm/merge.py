@@ -61,6 +61,45 @@ def empty_run() -> Run:
     return z, z.copy(), np.zeros(0, np.uint8), z.copy()
 
 
+def upsert(run: Run, chunk: Run) -> Run:
+    """``run`` with an unsorted ``chunk`` written over it, as
+    ``dict.update`` writes a chunk of items into a dict.
+
+    ``run`` is key-sorted with unique keys, and so is the result.  A
+    chunk key already in ``run`` overwrites its row and a new key is
+    inserted; among a chunk's duplicates the last by position wins,
+    whatever its seq.  O(len(run) + c log c) for a chunk of c rows.
+    ``run``'s arrays are left as they are: a reader holding them keeps
+    its snapshot.
+    """
+    order = np.argsort(chunk[0], kind="stable")
+    ks = chunk[0][order]
+    last = np.ones(len(ks), dtype=bool)
+    np.not_equal(ks[1:], ks[:-1], out=last[:-1])
+    pick = order[last]
+    rows = tuple(x[pick] for x in chunk)
+    mk = run[0]
+    m = len(mk)
+    if m == 0:
+        return rows
+    j = np.searchsorted(mk, rows[0])
+    old = j < m
+    old[old] = mk[j[old]] == rows[0][old]
+    new = ~old
+    nk = rows[0][new]
+    pa = np.arange(m) + np.searchsorted(nk, mk)  # run rows, shifted
+    pb = np.arange(len(nk)) + j[new]             # inserted rows
+    po = pa[j[old]]                              # overwritten rows
+    out = []
+    for x, c in zip(run, rows):
+        y = np.empty(m + len(nk), dtype=x.dtype)
+        y[pa] = x
+        y[pb] = c[new]
+        y[po] = c[old]
+        out.append(y)
+    return tuple(out)
+
+
 def merge_runs(parts: list[Run], empty: Run | None = None,
                rank_fn=None) -> Run:
     """Tournament-merge k key-sorted runs; duplicates stay adjacent.

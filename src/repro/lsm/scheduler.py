@@ -75,9 +75,9 @@ _PROACTIVE_PER_KICK = 4  # proactive compactions per drain point
 
 @dataclass
 class FrozenMemtable:
-    """One sealed, immutable memtable: the key-sorted columnar snapshot
-    (unique keys — the dict semantics already resolved overwrites) plus
-    the LRR range-tombstone buffer that sealed with it."""
+    """One sealed, immutable memtable: its key-sorted columnar run
+    (unique keys — the memtable already resolved overwrites) plus the
+    LRR range-tombstone buffer that sealed with it."""
 
     keys: np.ndarray
     seqs: np.ndarray

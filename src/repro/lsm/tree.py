@@ -20,7 +20,6 @@ from __future__ import annotations
 import threading
 from contextlib import contextmanager
 from dataclasses import dataclass
-from itertools import repeat
 
 import numpy as np
 
@@ -28,7 +27,7 @@ from ..core.gloran import GloranConfig, GloranIndex
 from ..core.iostats import IOStats
 from ..obs import span
 from .format import LSMConfig, PUT, TOMBSTONE
-from .merge import empty_run, merge_runs, merge_two, newest_wins
+from .merge import empty_run, merge_runs, merge_two, newest_wins, upsert
 from .scheduler import FrozenMemtable
 from .sstable import RangeTombstoneBlock, SSTable, build_sstable
 
@@ -65,8 +64,9 @@ class LSMTree:
         self.config = config or LSMConfig()
         self.strategy = strategy
         self.io = IOStats(block_size=self.config.block_size)
-        self.mem: dict[int, tuple[int, int, int]] = {}  # key->(seq,type,val)
-        self._mem_snap = None  # cached sorted snapshot; None = stale
+        # The memtable: one key-sorted run (keys, seqs, types, vals) with
+        # unique keys, which writes merge into and readers use as it is.
+        self.mem = empty_run()
         self.mem_rts: list[tuple[int, int, int]] = []  # LRR buffer
         self.levels: list[SSTable | None] = []
         self.level_rts: list[RangeTombstoneBlock] = []
@@ -138,10 +138,9 @@ class LSMTree:
             self._reserved = None
 
     def _mem_put(self, key: int, seq: int, typ: int, val: int) -> None:
-        self.mem[int(key)] = (int(seq), int(typ), int(val))
-        self._mem_snap = None
-        if len(self.mem) >= self.config.buffer_capacity:
-            self.flush()
+        self._mem_insert_batch(np.array([key], dtype=np.uint64),
+                               np.array([seq], dtype=np.uint64), typ,
+                               np.array([val], dtype=np.uint64))
 
     # ------------------------------------------------------------- writes
     def put(self, key: int, val: int) -> None:
@@ -165,31 +164,31 @@ class LSMTree:
                           typ: int, vals: np.ndarray | None) -> None:
         """Bulk memtable absorb, chunked at flush boundaries.
 
-        Each chunk is one ``dict.update`` of at most the remaining
-        buffer room, so the memtable can only reach capacity exactly at
-        a chunk end: within a chunk the entry count grows by at most one
-        per record and starts at least ``room`` below capacity, hence a
-        per-record loop could not have flushed mid-chunk either.  Flush
-        points (and therefore run shapes and I/O) are identical to
-        per-record inserts; later duplicates win inside a chunk exactly
-        as sequential overwrites would.
+        Each chunk of at most the remaining buffer room merges into the
+        sorted run as one ``dict.update`` would (``merge.upsert``), so
+        the memtable can only reach capacity exactly at a chunk end:
+        within a chunk the entry count grows by at most one per record
+        and starts at least ``room`` below capacity, hence a per-record
+        loop could not have flushed mid-chunk either.  Flush points (and
+        therefore run shapes and I/O) are identical to per-record
+        inserts; later duplicates win inside a chunk exactly as
+        sequential overwrites would.
         """
         n = len(keys)
-        kk = keys.tolist()
-        ss = seqs.tolist()
-        vv = vals.tolist() if vals is not None else None
-        self._mem_snap = None
+        seqs = np.asarray(seqs, dtype=np.uint64)
+        types = np.full(n, typ, dtype=np.uint8)
+        if vals is None:
+            vals = np.zeros(n, dtype=np.uint64)
+        cap = self.config.buffer_capacity
         at = 0
         while at < n:
-            room = self.config.buffer_capacity - len(self.mem)
-            take = min(max(room, 1), n - at)
-            end = at + take
-            payload = repeat(0, take) if vv is None else vv[at:end]
-            self.mem.update(zip(kk[at:end],
-                                zip(ss[at:end], repeat(typ, take),
-                                    payload)))
+            end = at + min(max(cap - len(self.mem[0]), 1), n - at)
+            with span("lsm.mem.merge", n=end - at) as sp:
+                self.mem = upsert(self.mem, (keys[at:end], seqs[at:end],
+                                             types[at:end], vals[at:end]))
+                sp.set(size=len(self.mem[0]))
             at = end
-            if len(self.mem) >= self.config.buffer_capacity:
+            if len(self.mem[0]) >= cap:
                 self.flush()
 
     def range_delete(self, lo: int, hi: int) -> None:
@@ -210,7 +209,7 @@ class LSMTree:
             self.mem_rts.append((int(lo), int(hi), self._next_seq()))
             # Range tombstones are memtable entries (RocksDB): they count
             # toward the buffer and flush with it.
-            if len(self.mem) + len(self.mem_rts) >= \
+            if len(self.mem[0]) + len(self.mem_rts) >= \
                     self.config.buffer_capacity:
                 self.flush()
         else:  # gloran
@@ -261,10 +260,11 @@ class LSMTree:
         """Point lookup; returns value or None."""
         key = int(key)
         rt_max = self._mem_rt_cover(key) if self.strategy == "lrr" else 0
-        hit = self.mem.get(key)
-        if hit is not None:
-            seq, typ, val = hit
-            return self._resolve(key, seq, typ, val, rt_max)
+        mk, ms, mt, mv = self.mem
+        j = int(np.searchsorted(mk, np.uint64(key)))
+        if j < len(mk) and mk[j] == key:
+            return self._resolve(key, int(ms[j]), int(mt[j]), int(mv[j]),
+                                 rt_max)
         if self.frozen:
             # Sealed snapshots sit between the active memtable and the
             # levels: newest first, memory-resident (no I/O charge).
@@ -338,11 +338,11 @@ class LSMTree:
                     m = (keys >= lo) & (keys < hi)
                     rt_max[m] = np.maximum(rt_max[m], np.uint64(s))
 
-            # Memtable: one sorted snapshot + batched binary search (skipped
+            # Memtable: the sorted run + batched binary search (skipped
             # entirely when empty — the steady post-flush state of
             # read-mostly serving).
-            if self.mem:
-                mk, ms, mt, mv = self._mem_sorted()
+            mk, ms, mt, mv = self.mem
+            if len(mk):
                 j = np.minimum(np.searchsorted(mk, keys), len(mk) - 1)
                 hitm = mk[j] == keys
                 jh = j[hitm]
@@ -439,23 +439,6 @@ class LSMTree:
                 out_found[sub] = False
         return out_found, out_vals
 
-    def _mem_sorted(self):
-        """Key-sorted snapshot of the memtable as a 4-array run, cached
-        until the next memtable mutation so read bursts between writes
-        (many lookup/scan batches against one buffered state) pay the
-        O(m log m) sort once, not per batch."""
-        if self._mem_snap is not None:
-            return self._mem_snap
-        m = len(self.mem)
-        if m == 0:
-            return empty_run()
-        keys = np.fromiter(self.mem.keys(), np.uint64, m)
-        rows = np.array(list(self.mem.values()), dtype=np.uint64)
-        order = np.argsort(keys)
-        self._mem_snap = (keys[order], rows[order, 0],
-                          rows[order, 1].astype(np.uint8), rows[order, 2])
-        return self._mem_snap
-
     def range_scan(self, lo: int, hi: int, *, validity_fn=None,
                    cache=None, rank_fn=None):
         """All live entries with lo <= key < hi. Returns (keys, vals)."""
@@ -468,7 +451,7 @@ class LSMTree:
 
         Each [lo, hi) produces the same (keys, vals) pair a per-call
         ``range_scan`` would, but the shared work is batched: the
-        memtable is snapshotted/sorted once, per-level slice bounds and
+        memtable run is sliced as it stands, per-level slice bounds and
         sequential-read charges are computed vectorized across all
         ranges, each range's slices are combined with a REMIX-style
         sorted-view merge (no per-scan lexsort), and LRR/GLORAN validity
@@ -489,7 +472,7 @@ class LSMTree:
             return []
         los = np.array([r[0] for r in ranges], dtype=np.uint64)
         his = np.array([r[1] for r in ranges], dtype=np.uint64)
-        mem = self._mem_sorted()
+        mem = self.mem
         m_lo = np.searchsorted(mem[0], los)
         m_hi = np.searchsorted(mem[0], his)
         # Frozen snapshots contribute one memory-resident slice each
@@ -560,16 +543,16 @@ class LSMTree:
 
     # -------------------------------------------------- flush / compaction
     def flush(self) -> None:
-        if not self.mem and not self.mem_rts:
+        if not len(self.mem[0]) and not self.mem_rts:
             return
         if self.scheduler is not None:
-            # Background mode: seal (cheap — the cached columnar
-            # snapshot) and let the scheduler flush/compact at the next
+            # Background mode: seal (cheap — the memtable run itself)
+            # and let the scheduler flush/compact at the next
             # drain point.  The foreground thread never pays the
             # cascade unless the frozen soft limit backpressures.
             self._seal()
             return
-        with span("lsm.flush", entries=len(self.mem),
+        with span("lsm.flush", entries=len(self.mem[0]),
                   range_tombstones=len(self.mem_rts)):
             self._flush()
 
@@ -577,15 +560,12 @@ class LSMTree:
         """Freeze the active memtable (and LRR buffer) into an
         immutable snapshot served by reads until a background flush
         job publishes it as a level-0 run."""
-        with span("lsm.seal", entries=len(self.mem),
+        with span("lsm.seal", entries=len(self.mem[0]),
                   range_tombstones=len(self.mem_rts),
                   backlog=len(self.frozen)):
-            mk, ms, mt, mv = self._mem_sorted()
             with self._struct_lock:
-                self.frozen.append(FrozenMemtable(mk, ms, mt, mv,
-                                                  self.mem_rts))
-                self.mem = {}
-                self._mem_snap = None
+                self.frozen.append(FrozenMemtable(*self.mem, self.mem_rts))
+                self.mem = empty_run()
                 self.mem_rts = []
                 self.struct_epoch += 1
         self.scheduler.on_seal()
@@ -618,13 +598,10 @@ class LSMTree:
                                      tag="rt_flush")
 
     def _flush(self) -> None:
-        if self.mem:
-            # The cached sorted columnar snapshot IS the run content:
-            # unique keys (dict semantics), key-sorted — no per-entry
-            # python loop, no lexsort in build_sstable (presorted).
-            mk, ms, mt, mv = self._mem_sorted()
-            self.mem.clear()
-            self._mem_snap = None
+        if len(self.mem[0]):
+            # The memtable run IS the level-0 run's content: unique
+            # keys, key-sorted — no lexsort in build_sstable (presorted).
+            (mk, ms, mt, mv), self.mem = self.mem, empty_run()
             self._sstable_seed += 1
             run = build_sstable(mk, ms, mt, mv, self.config, io=self.io,
                                 seed=self._sstable_seed, presorted=True)
@@ -775,8 +752,8 @@ class LSMTree:
 
     def _watermark(self, bottom_idx: int) -> int:
         w = self.seq
-        if self.mem:
-            w = min(w, min(s for s, _, _ in self.mem.values()))
+        if len(self.mem[1]):
+            w = min(w, int(self.mem[1].min()))
         for fz in self.frozen:
             # Sealed-but-unflushed entries are above the bottom level:
             # they hold the GC floor down exactly like the memtable.
@@ -791,7 +768,7 @@ class LSMTree:
     # ---------------------------------------------------------------- misc
     @property
     def num_entries(self) -> int:
-        return len(self.mem) + sum(len(f) for f in self.frozen) + sum(
+        return len(self.mem[0]) + sum(len(f) for f in self.frozen) + sum(
             len(l) for l in self.levels if l is not None)
 
     @property
@@ -803,7 +780,7 @@ class LSMTree:
 
     @property
     def memory_bytes(self) -> int:
-        mem = (len(self.mem) + sum(len(f) for f in self.frozen)) * \
+        mem = (len(self.mem[0]) + sum(len(f) for f in self.frozen)) * \
             self.config.entry_size
         blooms = sum(l.bloom.nbytes for l in self.levels if l is not None)
         fences = sum(

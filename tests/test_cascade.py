@@ -37,6 +37,7 @@ from repro.engine import Engine, EngineConfig
 from repro.kernels.cascade.ops import CascadeState, cascade_lookup
 from repro.kernels.cascade.ref import cascade_np
 from repro.lsm import LSMConfig, LSMTree, STRATEGIES
+from repro.lsm.scheduler import CompactionScheduler
 
 UNIVERSE = 1 << 20
 MODES = ("interpret", "compiled")
@@ -362,68 +363,186 @@ class TestKernelOracle:
 
 
 # ----------------------------------------- vectorized write/probe paths
+def run_rows(run) -> dict:
+    """A memtable run as ``key -> (seq, type, val)``."""
+    return {k: (s, t, v) for k, s, t, v in
+            zip(*(x.tolist() for x in run))}
+
+
 class LoopTree(LSMTree):
-    """The historical per-record write loops, as a parity reference."""
+    """The reference memtable: per-record writes into a test-local
+    ``dict`` (``ref``), flushed when it holds ``buffer_capacity``
+    entries.  ``mem`` is rebuilt from ``ref`` after every write call
+    and before every flush, so reads, seals and flushes see what a
+    dict memtable held."""
 
-    def put_batch(self, keys, vals):
-        keys = np.asarray(keys, dtype=np.uint64)
-        vals = np.asarray(vals, dtype=np.uint64)
-        seqs = self._next_seqs(len(keys))
-        for k, s, v in zip(keys.tolist(), seqs.tolist(), vals.tolist()):
-            self.mem[k] = (s, 0, v)
-            if len(self.mem) >= self.config.buffer_capacity:
-                self.flush()
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.ref = {}
 
-    def delete_batch(self, keys):
-        keys = np.asarray(keys, dtype=np.uint64)
-        seqs = self._next_seqs(len(keys))
-        for k, s in zip(keys.tolist(), seqs.tolist()):
-            self.mem[k] = (s, 1, 0)
-            if len(self.mem) >= self.config.buffer_capacity:
+    def _sync(self):
+        keys = sorted(self.ref)
+        rows = np.array([self.ref[k] for k in keys],
+                        dtype=np.uint64).reshape(-1, 3)
+        self.mem = (np.array(keys, dtype=np.uint64), rows[:, 0],
+                    rows[:, 1].astype(np.uint8), rows[:, 2])
+
+    def _mem_insert_batch(self, keys, seqs, typ, vals):
+        vals = np.zeros(len(keys), np.uint64) if vals is None else vals
+        for k, s, v in zip(np.asarray(keys).tolist(),
+                           np.asarray(seqs).tolist(),
+                           np.asarray(vals).tolist()):
+            self.ref[k] = (s, typ, v)
+            if len(self.ref) >= self.config.buffer_capacity:
                 self.flush()
+        self._sync()
+
+    def flush(self):
+        self._sync()
+        super().flush()
+        self.ref = {}
+
+
+def tree_pair(strategy="gloran", scheduler=False, **cfg):
+    a = LSMTree(small_cfg(**cfg), strategy=strategy,
+                gloran_config=small_gloran())
+    b = LoopTree(small_cfg(**cfg), strategy=strategy,
+                 gloran_config=small_gloran())
+    if scheduler:
+        for t in (a, b):
+            t.scheduler = CompactionScheduler(t, max_frozen=2)
+    return a, b
+
+
+def assert_same_state(a, b):
+    """Memtable contents, flush points, level shapes and I/O agree."""
+    assert a.seq == b.seq
+    assert run_rows(a.mem) == b.ref
+    for x, y in zip(a.mem, b.mem):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(x, y)
+    cols = ("keys", "seqs", "types", "vals")
+    assert [None if l is None else [getattr(l, c).tobytes() for c in cols]
+            for l in a.levels] == \
+        [None if l is None else [getattr(l, c).tobytes() for c in cols]
+         for l in b.levels]
+    assert [[getattr(f, c).tobytes() for c in cols] for f in a.frozen] == \
+        [[getattr(f, c).tobytes() for c in cols] for f in b.frozen]
+    assert a.io.snapshot() == b.io.snapshot()
+
+
+def assert_same_answers(a, b, probe):
+    fa, va = a.get_batch(probe)
+    fb, vb = b.get_batch(probe)
+    np.testing.assert_array_equal(fa, fb)
+    np.testing.assert_array_equal(va[fa], vb[fb])
+    for j, k in enumerate(probe.tolist()):
+        want = b.get(k)
+        assert a.get(k) == want, k
+        assert (int(va[j]) if fa[j] else None) == want, k
+    assert a.io.snapshot() == b.io.snapshot()
+
+
+def both(a, b, fn):
+    fn(a)
+    fn(b)
+    assert_same_state(a, b)
 
 
 class TestVectorizedWrites:
     @pytest.mark.parametrize("seed", (0, 1, 2))
     def test_flush_points_and_state_identical(self, seed):
-        """Chunked dict-update inserts == per-record inserts: same
+        """Chunked run merges == per-record dict inserts: same memtable,
         flush points, level shapes, I/O charges, and lookup answers
         (duplicates inside a batch keep last-wins order)."""
         rng = np.random.default_rng(seed)
-        a = LSMTree(small_cfg(), strategy="gloran",
-                    gloran_config=small_gloran())
-        b = LoopTree(small_cfg(), strategy="gloran",
-                     gloran_config=small_gloran())
+        a, b = tree_pair()
         for _ in range(6):
             keys = rng.integers(0, 500, size=150).astype(np.uint64)
             vals = rng.integers(0, 1 << 30, size=150).astype(np.uint64)
-            a.put_batch(keys, vals)
-            b.put_batch(keys, vals)
+            both(a, b, lambda t: t.put_batch(keys, vals))
             dels = rng.integers(0, 500, size=40).astype(np.uint64)
-            a.delete_batch(dels)
-            b.delete_batch(dels)
-            assert a.seq == b.seq
-            assert a.mem == b.mem
-            assert [len(l) if l is not None else 0 for l in a.levels] == \
-                [len(l) if l is not None else 0 for l in b.levels]
-        assert a.io.snapshot() == b.io.snapshot()
-        probe = rng.integers(0, 600, size=400).astype(np.uint64)
-        fa, va = a.get_batch(probe)
-        fb, vb = b.get_batch(probe)
-        np.testing.assert_array_equal(fa, fb)
-        np.testing.assert_array_equal(va[fa], vb[fb])
+            both(a, b, lambda t: t.delete_batch(dels))
+        assert_same_answers(a, b,
+                            rng.integers(0, 600, size=400).astype(np.uint64))
+
+    @pytest.mark.parametrize("strategy", STRATEGIES)
+    @pytest.mark.parametrize("scheduler", (False, True))
+    def test_dict_oracle_every_strategy(self, strategy, scheduler):
+        """Random write calls of every size (in-batch duplicates,
+        tombstones, scalar puts and deletes, range deletes), inline or
+        sealing into a compaction scheduler drained at random points,
+        leave the run memtable where the dict memtable leaves it."""
+        rng = np.random.default_rng(STRATEGIES.index(strategy))
+        a, b = tree_pair(strategy, scheduler)
+        for _ in range(40):
+            n = int(rng.choice([1, 7, 63, 64, 65, 200]))
+            keys = rng.integers(0, 300, size=n).astype(np.uint64)
+            op = rng.integers(0, 5)
+            if op == 0:
+                both(a, b, lambda t: t.put_batch(keys, keys * np.uint64(3)))
+            elif op == 1:
+                both(a, b, lambda t: t.delete_batch(keys))
+            elif op == 2:
+                k = int(keys[0])
+                both(a, b, lambda t: (t.put(k, k + 1), t.delete(k + 1)))
+            elif op == 3:
+                lo = int(keys[0])
+                both(a, b, lambda t: t.range_delete(lo, lo + 9))
+            elif scheduler:
+                both(a, b, lambda t: t.scheduler.run_due())
+            else:
+                both(a, b, lambda t: t.flush())
+        assert_same_answers(a, b, np.arange(0, 320, dtype=np.uint64))
+
+    @pytest.mark.parametrize("fill", ("exact", "dups", "over"))
+    def test_fill_ends_at_capacity(self, fill):
+        """A batch whose distinct keys end exactly at capacity flushes
+        once, at its end; duplicates that keep the count one below do
+        not flush; one key past capacity starts the next memtable."""
+        a, b = tree_pair(buffer_capacity=64)
+        both(a, b, lambda t: t.put_batch(
+            np.arange(10, dtype=np.uint64), np.arange(10, dtype=np.uint64)))
+        keys = np.arange(10, 64, dtype=np.uint64)  # 54 new: exactly full
+        if fill == "dups":
+            keys = np.concatenate([keys[:-1], keys[:5]])
+        elif fill == "over":
+            keys = np.concatenate([keys, [np.uint64(999)]])
+        both(a, b, lambda t: t.put_batch(keys, keys + np.uint64(7)))
+        flushed = [len(l) for l in a.levels if l is not None]
+        assert len(a.mem[0]) == {"exact": 0, "dups": 63, "over": 1}[fill]
+        assert flushed == ([] if fill == "dups" else [64])
+
+    def test_reserved_seqs_last_arrival_wins(self):
+        """Under ``reserved_seqs`` the seqs may fall as the records
+        arrive: among a chunk's duplicates the later record still
+        wins, as ``dict.update`` ordered it, not the higher seq; and
+        ``seq`` reads the reserved last one at every flush."""
+        a, b = tree_pair(buffer_capacity=16)
+        rng = np.random.default_rng(5)
+        last = 0
+        for _ in range(12):
+            n = int(rng.integers(1, 40))
+            keys = rng.integers(0, 24, size=n).astype(np.uint64)
+            seqs = (last + rng.permutation(n) + 1).astype(np.uint64)
+            last += n
+
+            def write(t):
+                with t.reserved_seqs(seqs, last):
+                    t.put_batch(keys, keys + np.uint64(1))
+            both(a, b, write)
+        assert_same_answers(a, b, np.arange(0, 30, dtype=np.uint64))
 
     def test_memtable_probe_matches_scalar_get(self):
-        """The sorted-snapshot memtable stage answers exactly what the
-        per-key dict path (scalar ``get``) answers, tombstones
-        included."""
+        """The sorted-run memtable stage answers exactly what the
+        scalar ``get`` answers, tombstones included."""
         t = LSMTree(small_cfg(buffer_capacity=1 << 30), strategy="gloran",
                     gloran_config=small_gloran())
         rng = np.random.default_rng(8)
         keys = rng.integers(0, 300, size=200).astype(np.uint64)
         t.put_batch(keys, keys + np.uint64(1))
         t.delete_batch(rng.integers(0, 300, size=50).astype(np.uint64))
-        assert t.mem  # everything still buffered
+        assert len(t.mem[0])  # everything still buffered
         probe = np.arange(0, 320, dtype=np.uint64)
         f, v = t.get_batch(probe)
         for j, k in enumerate(probe.tolist()):

@@ -320,6 +320,7 @@ def test_shard_host_spans_nest_under_their_step():
     # entries range deletes have covered.
     under = {"gloran.eve": ("shard.range_delete",),
              "lsm.get.mem": ("shard.get",), "lsm.get.levels": ("shard.get",),
+             "lsm.mem.merge": ("shard.put", "shard.delete"),
              "gloran.validity": ("shard.get", "lsm.compact")}
     for child, parents in under.items():
         mine = [e for e in evs if e["name"] == child]

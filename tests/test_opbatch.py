@@ -418,7 +418,8 @@ def shard_state(sh) -> dict:
     t = sh.tree
     cols = ("keys", "seqs", "types", "vals")
     state = {
-        "seq": t.seq, "io": t.io.snapshot(), "mem": dict(t.mem),
+        "seq": t.seq, "io": t.io.snapshot(),
+        "mem": [x.tobytes() for x in t.mem],
         "levels": [None if lvl is None else
                    [getattr(lvl, c).tobytes() for c in cols]
                    for lvl in t.levels],
